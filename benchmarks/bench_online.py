@@ -27,7 +27,8 @@ plus dispatch-count rows (jit-launch counter, repro.launch.trace):
                               partitioned engine, fused (=1) vs the
                               assemble host-path baseline (reassembly +
                               estimate)
-and, per device count D (subprocess with host-platform device forcing):
+and, per device count D (every mesh size up to the devices this process
+sees; on CPU force them with --xla_force_host_platform_device_count):
   online_ingest_fused1_dD         fused single-dispatch, replicated views
   online_ingest_fused1_part_dD    fused single-dispatch, partitioned views
   online_ingest_dD                planner path, replicated views
@@ -66,10 +67,7 @@ verdict and invalidates touched cache entries per ingest:
 
 REPRO_BENCH_SMOKE=1 shrinks N for CI smoke runs (full mode: N = 2^20).
 """
-import os
-import subprocess
 import sys
-import textwrap
 import time
 
 import numpy as np
@@ -143,97 +141,68 @@ def _steady_dispatches(eng, bs, seed0):
     return n()
 
 
-_SWEEP_SCRIPT = """
-import json, os, time
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={ndev}"
-import numpy as np
-from benchmarks.bench_online import SPECS, TREATMENTS, _gen
-from repro.core import OnlineEngine, PartitionedOnlineEngine
-from repro.data.columnar import Table
-from repro.launch.mesh import make_data_mesh
-
-mesh = make_data_mesh({ndev}) if {ndev} > 1 else None
-out = {{}}
-engines = {{}}
-for label, cls, kw in (
-        ("fused1", OnlineEngine, dict()),
-        ("fused1_part", PartitionedOnlineEngine,
-         dict(n_parts=None if {ndev} > 1 else 1)),
-        ("replicated", OnlineEngine, dict(pipeline="planner")),
-        ("partitioned", PartitionedOnlineEngine,
-         dict(pipeline="planner", n_parts=None if {ndev} > 1 else 1))):
-    eng = cls.from_table(Table.from_numpy(_gen({n}, seed=0)),
-                         SPECS, TREATMENTS, "y", mesh=mesh, **kw)
-    engines[label] = eng
-    feed = [Table.from_numpy(_gen({bs}, seed=1 + i))
-            for i in range({warmup} + {iters})]
-    for b in feed[:{warmup}]:
-        eng.ingest(b)
+def _sweep_one(ndev: int, n: int, bs: int, warmup: int, iters: int):
+    """One mesh size of :func:`sharded_sweep`, in this process: the four
+    engine variants' median ingest seconds and resident bytes, then the
+    partitioned fused engine's uncached query and row lookup."""
+    from repro.launch.mesh import make_data_mesh
+    mesh = make_data_mesh(ndev) if ndev > 1 else None
+    res, engines = {}, {}
+    for label, cls, kw in (
+            ("fused1", OnlineEngine, dict()),
+            ("fused1_part", PartitionedOnlineEngine,
+             dict(n_parts=None if ndev > 1 else 1)),
+            ("replicated", OnlineEngine, dict(pipeline="planner")),
+            ("partitioned", PartitionedOnlineEngine,
+             dict(pipeline="planner", n_parts=None if ndev > 1 else 1))):
+        eng = cls.from_table(Table.from_numpy(_gen(n, seed=0)),
+                             SPECS, TREATMENTS, "y", mesh=mesh, **kw)
+        engines[label] = eng
+        feed = [Table.from_numpy(_gen(bs, seed=1 + i))
+                for i in range(warmup + iters)]
+        for b in feed[:warmup]:
+            eng.ingest(b)
+        ts = []
+        for b in feed[warmup:]:
+            t0 = time.perf_counter()
+            eng.ingest(b)
+            ts.append(time.perf_counter() - t0)
+        res[label] = dict(secs=float(np.median(ts)), **eng.state_bytes())
+    # device-resident query pipeline on the partitioned fused engine:
+    # uncached fused ate() (one dispatch + one scalar fetch) and the fused
+    # row-lookup probe (routed over the mesh when ndev > 1)
+    qeng = engines["fused1_part"]
+    probe = Table.from_numpy(_gen(4096, seed=777))
+    for _ in range(warmup):
+        qeng._cache.clear()
+        qeng.ate("t")
+        qeng.matched_rows("t", probe).block_until_ready()
     ts = []
-    for b in feed[{warmup}:]:
+    for _ in range(iters):
+        qeng._cache.clear()
         t0 = time.perf_counter()
-        eng.ingest(b)
+        qeng.ate("t")
         ts.append(time.perf_counter() - t0)
-    out[label] = dict(secs=float(np.median(ts)), **eng.state_bytes())
-# device-resident query pipeline on the partitioned fused engine:
-# uncached fused ate() (one dispatch + one scalar fetch) and the fused
-# row-lookup probe (routed over the mesh when {ndev} > 1)
-qeng = engines["fused1_part"]
-probe = Table.from_numpy(_gen(4096, seed=777))
-for _ in range({warmup}):
-    qeng._cache.clear()
-    qeng.ate("t")
-    m = qeng.matched_rows("t", probe)
-    m.block_until_ready()
-ts = []
-for _ in range({iters}):
-    qeng._cache.clear()
-    t0 = time.perf_counter()
-    qeng.ate("t")
-    ts.append(time.perf_counter() - t0)
-out["query_fused_part"] = dict(secs=float(np.median(ts)))
-ts = []
-for _ in range({iters}):
-    t0 = time.perf_counter()
-    m = qeng.matched_rows("t", probe)
-    m.block_until_ready()
-    ts.append(time.perf_counter() - t0)
-out["rowlookup_part"] = dict(secs=float(np.median(ts)))
-print("SWEEP_RESULT", json.dumps(out))
-"""
+    res["query_fused_part"] = dict(secs=float(np.median(ts)))
+    ts = []
+    for _ in range(iters):
+        t0 = time.perf_counter()
+        qeng.matched_rows("t", probe).block_until_ready()
+        ts.append(time.perf_counter() - t0)
+    res["rowlookup_part"] = dict(secs=float(np.median(ts)))
+    return res
 
 
 def sharded_sweep(n: int, bs: int, device_counts, warmup=WARMUP,
                   iters=ITERS):
     """Per-batch ingest latency + per-device resident state per data-mesh
     size: fused single-dispatch vs planner, replicated vs partitioned
-    views. Host-platform device forcing needs a fresh process per count
-    (XLA_FLAGS is read once)."""
-    import json
+    views. Every mesh is built in THIS process from the devices it
+    already sees (one process holds the chip); a CPU caller forces host
+    devices with ``XLA_FLAGS=--xla_force_host_platform_device_count=D``
+    before start. A failed mesh size raises."""
     for ndev in device_counts:
-        code = textwrap.dedent(_SWEEP_SCRIPT.format(
-            ndev=ndev, n=n, bs=bs, warmup=warmup, iters=iters))
-        proc = None
-        try:
-            proc = subprocess.run(
-                [sys.executable, "-c", code], capture_output=True,
-                text=True, timeout=1800,
-                env={**os.environ, "PYTHONPATH": "src:."})
-            marker = [ln for ln in proc.stdout.splitlines()
-                      if ln.startswith("SWEEP_RESULT")]
-            if proc.returncode != 0 or not marker:
-                raise RuntimeError(f"rc={proc.returncode}, "
-                                   f"marker={'yes' if marker else 'no'}")
-            res = json.loads(marker[-1].split(" ", 1)[1])
-        except (subprocess.TimeoutExpired, RuntimeError,
-                ValueError, IndexError) as e:
-            # warn-and-continue; no emit — a 0.0 datapoint would read as
-            # infinitely fast ingest in the benchmark artifact
-            print(f"online_ingest_d{ndev} sweep FAILED: {e}",
-                  file=sys.stderr)
-            if proc is not None:
-                print(proc.stderr[-2000:], file=sys.stderr)
-            continue
+        res = _sweep_one(ndev, n, bs, warmup, iters)
         rep, part = res["replicated"], res["partitioned"]
         f1, f1p = res["fused1"], res["fused1_part"]
         emit(f"online_ingest_fused1_d{ndev}", f1["secs"],
@@ -597,9 +566,12 @@ def main() -> None:
     finally:
         shutil.rmtree(repl_dir, ignore_errors=True)
 
-    # sharded ingest: per-batch latency per device-mesh size
+    # sharded ingest: per-batch latency per device-mesh size, over the
+    # devices this process sees
+    import jax
     sweep_n = 1 << 15 if smoke() else 1 << 18
-    device_counts = (1, 2) if smoke() else (1, 2, 4, 8)
+    device_counts = [d for d in ((1, 2) if smoke() else (1, 2, 4, 8))
+                     if d <= len(jax.devices())]
     sharded_sweep(sweep_n, 4096, device_counts)
 
 

@@ -31,6 +31,8 @@ def main() -> None:
                     help="comma-separated subset of suite names")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (bench_e2e, bench_kernels, bench_online,
                             bench_optimizations, bench_quality,
                             bench_roofline, bench_scalability, common)

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 
 from repro.core import (CoarsenSpec, cem, difference_in_means, estimate_ate)
 from repro.data.columnar import Table
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.train import PRESETS
 from repro.models import forward, init_params
 
@@ -98,4 +99,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -23,6 +23,7 @@ from repro.core import (CoarsenSpec, awmd, cem, cem_join_pushdown,
 from repro.data import flightgen
 from repro.data.columnar import Table
 from repro.data.join import fk_join
+from repro.launch.compile_cache import enable_compile_cache
 
 SPEC_RANGES = {"w_precipm": (0, 3), "w_wspdm": (0, 80), "w_hum": (0, 100),
                "w_tempm": (-20, 40)}
@@ -137,4 +138,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -53,6 +53,7 @@ from repro.core import (CoarsenSpec, OnlineEngine, PartitionedOnlineEngine,
 from repro.data import flightgen
 from repro.data.columnar import Table
 from repro.data.join import fk_join
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_data_mesh
 
 SPEC_RANGES = {"w_precipm": (0, 3), "w_wspdm": (0, 80), "w_tempm": (-20, 40)}
@@ -227,4 +228,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

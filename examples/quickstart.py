@@ -11,6 +11,9 @@ import numpy as np
 from repro.core import (CoarsenSpec, awmd, cem, difference_in_means,
                         estimate_ate, raw_imbalance)
 from repro.data.columnar import Table
+from repro.launch.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 # --- observational data with a confounder -------------------------------
 rng = np.random.default_rng(0)

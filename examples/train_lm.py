@@ -15,6 +15,7 @@ import argparse
 import shutil
 import tempfile
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.train import PRESETS, run
 
 
@@ -42,4 +43,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

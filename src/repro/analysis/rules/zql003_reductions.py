@@ -7,8 +7,8 @@ partition count and mesh size. A bare ``jnp.sum`` over a
 capacity-dependent axis re-associates when the capacity grows and
 ``jax.lax.psum`` re-associates with the device count, so estimator
 bodies must route cross-group float reductions through
-``kernels.segment_stats.chunked_sum`` (fixed canonical block size,
-strictly sequential combine).
+``kernels.segment_stats.canonical_sum`` (pairwise fold whose
+association is fixed in the program).
 
 Scope: functions whose name contains ``estimate`` in engine-owned
 modules — the canonical estimator bodies. Integer/bool count reductions
@@ -51,7 +51,7 @@ def _is_exact_count(call: ast.Call, aliases) -> bool:
 class Rule:
     id = "ZQL003"
     summary = ("order-sensitive reduction in an estimator body "
-               "(use kernels.segment_stats.chunked_sum)")
+               "(use kernels.segment_stats.canonical_sum)")
 
     def check(self, ctx: ModuleContext) -> Iterator[Finding]:
         if not ctx.engine_owned:
@@ -75,7 +75,7 @@ class Rule:
                     f"`{canon}` in estimator body `{fn.name}` — "
                     "order-sensitive float reduction breaks the "
                     "bit-identity contract; route through "
-                    "kernels.segment_stats.chunked_sum (or inject via "
+                    "kernels.segment_stats.canonical_sum (or inject via "
                     "sum_fn=)")
 
 

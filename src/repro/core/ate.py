@@ -79,7 +79,7 @@ def estimate_ate_from_stats(keep: jnp.ndarray, n_treated: jnp.ndarray,
 
     ``sum_fn`` is the cross-group reduction. The online query pipelines
     pass the capacity-invariant canonical sum
-    (:func:`repro.kernels.segment_stats.chunked_sum`), which makes the
+    (:func:`repro.kernels.segment_stats.canonical_sum`), which makes the
     estimate a bitwise-deterministic function of the key-sorted group
     content ALONE — independent of padded vector length, partition count
     or capacity-growth history — so the replicated, partitioned, fused

@@ -2,7 +2,6 @@
 
 Two TPU-native communication patterns replace the single-node SQL engine
 (design rationale in DESIGN.md §2):
-
 COMBINE-BROADCAST GROUP-BY (CEM, subclassification, cuboids):
   1. each device groups its row shard locally (sort + segment stats — the
      paper's Fig. 5 view, per shard);
@@ -120,12 +119,11 @@ def make_distributed_cem(mesh, capacity: int = 8192,
                 est.n_matched_treated, est.n_matched_control, matched,
                 any_overflow)
 
-    from jax.experimental.shard_map import shard_map
-    fn = shard_map(
+    fn = jax.shard_map(
         shard_body, mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis), P(axis), P(axis)),
         out_specs=(P(), P(), P(), P(), P(), P(), P(axis), P()),
-        check_rep=False)
+        check_vma=False)
     from repro.launch.trace import counted_jit
     return counted_jit(fn)
 
@@ -190,11 +188,10 @@ def make_sharded_delta_build(mesh, specs: Mapping, treatments: Sequence[str],
                              treatments=tuple(treatments), outcome=outcome,
                              capacity=capacity, axis=axis)
 
-    from jax.experimental.shard_map import shard_map
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P(axis), P(axis)),
-                   out_specs=(P(), P(), P(), P(), P(), P()),
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(axis), P(axis)),
+                       out_specs=(P(), P(), P(), P(), P(), P()),
+                       check_vma=False)
     from repro.launch.trace import counted_jit
     return counted_jit(fn)
 
@@ -317,16 +314,15 @@ def make_routed_delta_build(mesh, specs: Mapping, treatments: Sequence[str],
                              capacity=capacity, view_items=view_items,
                              n_parts=n_parts, n_dev=n_dev, axis=axis)
 
-    from jax.experimental.shard_map import shard_map
     part = P(axis, None)
     out_deltas = {name: (part, part,
                          {k: part for k in cube_mod.stat_names(treatments)},
                          part)
                   for name, _ in view_items}
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P(axis), P(axis)),
-                   out_specs=(out_deltas, P(), P()),
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(axis), P(axis)),
+                       out_specs=(out_deltas, P(), P()),
+                       check_vma=False)
     from repro.launch.trace import counted_jit
     return counted_jit(fn)
 
@@ -397,12 +393,11 @@ def make_routed_row_lookup(mesh, specs: Mapping, view_dims: Sequence[str],
                          f"data-axis size {n_dev}")
     body = functools.partial(_routed_lookup_body, codec=codec, specs=vspecs,
                              n_parts=n_parts, n_dev=n_dev, axis=axis)
-    from jax.experimental.shard_map import shard_map
     part = P(axis, None)
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P(axis), P(axis), part, part, part),
-                   out_specs=P(axis),
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(axis), P(axis), part, part, part),
+                       out_specs=P(axis),
+                       check_vma=False)
     from repro.launch.trace import counted_jit
     return counted_jit(fn, label="query")
 
@@ -445,11 +440,10 @@ def make_ring_knn(mesh, k: int, axis: str = "data"):
             jnp.arange(n_dev))
         return jnp.sqrt(run_d), run_i
 
-    from jax.experimental.shard_map import shard_map
-    fn = shard_map(shard_body, mesh=mesh,
-                   in_specs=(P(axis), P(axis), P(axis)),
-                   out_specs=(P(axis), P(axis)),
-                   check_rep=False)
+    fn = jax.shard_map(shard_body, mesh=mesh,
+                       in_specs=(P(axis), P(axis), P(axis)),
+                       out_specs=(P(axis), P(axis)),
+                       check_vma=False)
     from repro.launch.trace import counted_jit
     return counted_jit(fn)
 
@@ -478,10 +472,9 @@ def make_distributed_newton(mesh, n_iter: int = 32, ridge: float = 1e-4,
         w, _ = jax.lax.scan(step, w0, None, length=n_iter)
         return w
 
-    from jax.experimental.shard_map import shard_map
-    fn = shard_map(shard_body, mesh=mesh,
-                   in_specs=(P(axis), P(axis), P(axis)),
-                   out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(shard_body, mesh=mesh,
+                       in_specs=(P(axis), P(axis), P(axis)),
+                       out_specs=P(),
+                       check_vma=False)
     from repro.launch.trace import counted_jit
     return counted_jit(fn)

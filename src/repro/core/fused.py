@@ -33,19 +33,20 @@ Growth is device-resident too: the re-sort branch merges at the CURRENT
 capacity and reports ``grew`` when the merged group count would not fit;
 the engine then pads the (pass-through, unmodified) state and re-dispatches
 the same program compiled at the doubled capacity — a recompile keyed on
-``(granule count, n_parts)``, so a stream that stops growing stops
-recompiling. Only the delta-capacity overflow (more distinct groups in one
-batch than the delta table holds) still falls back to the exact host
-rebuild, exactly as before.
+``(capacity, n_parts)``, so a stream that stops growing stops
+recompiling. Delta-capacity overflow (more distinct groups in one batch
+than the delta table holds) is handled the same way: the state passes
+through, the engine doubles the delta capacity and re-dispatches.
 
 Programs are cached at module level (``functools.lru_cache``) keyed on the
 full schema + capacity signature, so every engine with the same shapes
 shares one compilation.
 
-QUERIES get the same treatment (PR 5): :func:`get_fused_query` answers an
-uncached ``ate()`` with ONE compiled dispatch straight on the raw
-(replicated or partitioned) view state — subpopulation filter + keep mask
-per partition, in-program canonical key-sort, capacity-invariant chunked
+QUERIES get the same treatment: :func:`get_fused_query_batch` answers
+an uncached ``ate()`` (a one-spec wave) or a whole ``ate_batch`` wave with
+ONE compiled dispatch straight on the raw (replicated or partitioned)
+view state — subpopulation filter + keep mask from spec rows that are
+data, in-program canonical key-sort, capacity-invariant canonical
 reductions — and :func:`get_fused_rowlookup` answers ``matched_rows``
 with one dispatch (routed all-to-all probe on a partitioned mesh). Query
 programs take state BY REFERENCE (never donated) and return only scalars
@@ -67,7 +68,7 @@ from repro.core.ate import estimate_ate_from_stats
 from repro.core.cem import overlap_keep, update_overlap
 from repro.core.keys import INVALID_HI, INVALID_LO
 from repro.core.propensity import _stream_retract, _stream_update
-from repro.kernels.segment_stats import chunked_sum
+from repro.kernels.segment_stats import canonical_sum
 from repro.launch.trace import counted_jit, hot_path
 
 #: contract-lint scoping (tools/contract_check.py): this module is
@@ -381,8 +382,6 @@ def get_fused_ingest(codec, specs_items, tnames: Tuple[str, ...],
         return new_state, out
 
     if ndev > 1:
-        from jax.experimental.shard_map import shard_map
-
         from repro.core.distributed import _sharded_delta_body
         build = functools.partial(_sharded_delta_body, codec=codec,
                                   specs=specs, treatments=tnames,
@@ -394,11 +393,11 @@ def get_fused_ingest(codec, specs_items, tnames: Tuple[str, ...],
 
         def program(columns, valid, state, counter, n_batches):
             pcols, pvalid = _pad_batch(columns, valid, ndev)
-            new_views, out = shard_map(
+            new_views, out = jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(P(mesh_axis), P(mesh_axis), P(), P()),
                 out_specs=(P(), P()),
-                check_rep=False)(pcols, pvalid, state["views"], counter)
+                check_vma=False)(pcols, pvalid, state["views"], counter)
             return finish(new_views, out, state, columns, valid, n_batches)
     else:
         def program(columns, valid, state, counter, n_batches):
@@ -480,8 +479,6 @@ def get_fused_ingest_parts(codec, specs_items, tnames: Tuple[str, ...],
         return new_state, out
 
     if ndev > 1:
-        from jax.experimental.shard_map import shard_map
-
         from repro.core.distributed import _routed_delta_body
         build = functools.partial(
             _routed_delta_body, codec=codec, specs=specs,
@@ -501,11 +498,11 @@ def get_fused_ingest_parts(codec, specs_items, tnames: Tuple[str, ...],
 
         def program(columns, valid, state, counter, n_batches):
             pcols, pvalid = _pad_batch(columns, valid, ndev)
-            new_views, out = shard_map(
+            new_views, out = jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(P(mesh_axis), P(mesh_axis), part, P()),
                 out_specs=(part, out_spec),
-                check_rep=False)(pcols, pvalid, state["views"], counter)
+                check_vma=False)(pcols, pvalid, state["views"], counter)
             return finish(new_views, out, state, columns, valid, n_batches)
     else:
         def single_build(columns, valid):
@@ -563,7 +560,7 @@ def _estimate_from_roles(hi, lo, stats, m):
     keys into the canonical (globally key-sorted, valid-prefix) order —
     keys are unique across partitions, so the segment sums are exact
     gathers — then reduce with the capacity-invariant canonical sum
-    (:func:`repro.kernels.segment_stats.chunked_sum`). The result is a
+    (:func:`repro.kernels.segment_stats.canonical_sum`). The result is a
     bitwise-deterministic function of the surviving group stats alone:
     identical for replicated/partitioned layouts, any partition count, any
     capacity history, and identical to the ``assemble`` baseline path.
@@ -586,7 +583,7 @@ def _estimate_from_roles(hi, lo, stats, m):
     yyt = sums["yyt"]
     yyc = sums["yy"] - yyt
     est = estimate_ate_from_stats(keep, nt, nc, yt, yc, sum_yy_t=yyt,
-                                  sum_yy_c=yyc, sum_fn=chunked_sum)
+                                  sum_yy_c=yyc, sum_fn=canonical_sum)
     return dict(ate=est.ate, att=est.att,
                 n_matched_treated=est.n_matched_treated,
                 n_matched_control=est.n_matched_control,
@@ -614,56 +611,18 @@ def estimate_view_body(hi, lo, stats, gv, keep, *, codec, treatment,
 
 
 @functools.lru_cache(maxsize=512)
-def get_fused_query(codec, treatment: str, subpop, mesh, mesh_axis: str,
-                    partitioned: bool):
-    """One-dispatch causal query program: ``f(hi, lo, stats, gv, keep) ->
-    {ate, att, n_matched_*, n_groups, variance}`` over a view's raw
-    materialized state — replicated ``(C,)`` or partitioned ``(P, C)`` —
-    with NO host-side reassembly or compaction anywhere on the path. The
-    engine fetches the scalar dict with one ``device_get`` and caches it;
-    steady state is exactly one compiled dispatch per uncached query.
-
-    On a mesh with partitioned state the program is a single ``shard_map``
-    body: subpopulation filtering and keep masking run PER PARTITION on
-    the owning device (per-device work/state ~1/N), then only the tiny
-    masked key+stat vectors cross the interconnect (one ``all_gather``)
-    and every device runs the identical canonical reduce. The final
-    reduce is deliberately replicated rather than ``psum``-composed:
-    a psum's float association would depend on the partition count, while
-    the canonical chunked reduction is what keeps the estimate bit-
-    identical across 1/2/4-device meshes, any ``n_parts``, and the
-    replicated engine. ``subpop`` is the frozen subpopulation predicate
-    (part of the program cache key, like every shape/schema input)."""
-    ndev = 1 if mesh is None else int(mesh.shape[mesh_axis])
-
-    if partitioned and ndev > 1:
-        from jax.experimental.shard_map import shard_map
-
-        def body(hi, lo, stats, gv, keep):
-            # local (k, C) slices: mask per partition, gather the masked
-            # tables, estimate replicated (same bits on every device)
-            m = _query_mask(hi, lo, gv, keep, codec, subpop)
-            chi = jnp.where(m, hi, INVALID_HI)
-            clo = jnp.where(m, lo, INVALID_LO)
-            cstats = {k: jnp.where(m, v, 0.0) for k, v in stats.items()}
-            ghi = jax.lax.all_gather(chi, mesh_axis, tiled=True)
-            glo = jax.lax.all_gather(clo, mesh_axis, tiled=True)
-            gstats = {k: jax.lax.all_gather(v, mesh_axis, tiled=True)
-                      for k, v in cstats.items()}
-            gm = ~((ghi == INVALID_HI) & (glo == INVALID_LO))
-            return _estimate_from_masked(ghi, glo, gstats, gm, treatment)
-
-        part = P(mesh_axis, None)
-
-        def program(hi, lo, stats, gv, keep):
-            return shard_map(body, mesh=mesh,
-                             in_specs=(part, part, part, part, part),
-                             out_specs=P(),
-                             check_rep=False)(hi, lo, stats, gv, keep)
-    else:
-        def program(hi, lo, stats, gv, keep):
-            return estimate_view_body(hi, lo, stats, gv, keep, codec=codec,
-                                      treatment=treatment, subpop=subpop)
+def get_fused_query(codec, treatment: str, subpop):
+    """One-dispatch causal query program with the subpopulation predicate
+    in its trace: ``f(hi, lo, stats, gv, keep) -> {ate, att,
+    n_matched_*, n_groups, variance}`` over one replicated ``(C,)`` view
+    (the ``query_pipeline="assemble"`` baseline feeds it the reassembled
+    canonical view). ``subpop`` is the frozen predicate and part of the
+    cache key, so each distinct subpopulation compiles its own program;
+    the default ``ate()`` path is the batched program below at B=1, whose
+    spec is data."""
+    def program(hi, lo, stats, gv, keep):
+        return estimate_view_body(hi, lo, stats, gv, keep, codec=codec,
+                                  treatment=treatment, subpop=subpop)
 
     return counted_jit(program, label="query")
 
@@ -686,7 +645,7 @@ def get_fused_query(codec, treatment: str, subpop, mesh, mesh_axis: str,
 # The per-group predicate test becomes one gather + bit-test per dim —
 # exactly the same boolean mask _query_mask builds by unrolled equality,
 # so the downstream canonical estimate (shared `_estimate_from_roles`
-# body, capacity-invariant chunked_sum reduce) returns bit-identical
+# body, capacity-invariant canonical_sum reduce) returns bit-identical
 # answers, while the program itself is cached on SHAPES ONLY (view
 # schema, word layout, pow2 spec-count bucket) — any B specs with any
 # predicates run through ONE compiled dispatch with no retrace.
@@ -769,7 +728,7 @@ def _batched_query_body(view_schema, cards, offsets, view_states,
     gather by view id; padding cannot perturb the answer because the
     canonical reduce is bitwise invariant to trailing invalid/zero tail
     (the same contract that makes capacity growth and partition count
-    invisible — see ``chunked_sum``). Estimates run once per SPEC (not
+    invisible — see ``canonical_sum``). Estimates run once per SPEC (not
     per spec x view): masks are evaluated per view (each view's codec is
     static), then each spec gathers its own view's mask row."""
     sizes = [int(np.prod(st[0].shape))  # zql: ok[ZQL002] static shapes
@@ -832,14 +791,12 @@ def get_fused_query_batch(view_schema, cards, b_bucket: int, mesh,
     ``shard_map`` body that all_gathers each view's raw partition tables
     ONCE (state-sized traffic, not B masked copies) and then runs the
     identical replicated batched estimate — the final reduce stays the
-    canonical chunked reduction, never a psum, so answers are
+    canonical pairwise reduction, never a psum, so answers are
     bit-identical to the B=1 fused path on 1/2/4-device meshes."""
     offsets, _ = spec_word_layout(cards)
     ndev = 1 if mesh is None else int(mesh.shape[mesh_axis])
 
     if partitioned and ndev > 1:
-        from jax.experimental.shard_map import shard_map
-
         def sm_body(view_states, spec_rows):
             def g(x):
                 return jax.lax.all_gather(x, mesh_axis, tiled=True)
@@ -855,9 +812,9 @@ def get_fused_query_batch(view_schema, cards, b_bucket: int, mesh,
             for _ in view_schema)
 
         def program(view_states, spec_rows):
-            return shard_map(sm_body, mesh=mesh,
-                             in_specs=(state_spec, P()), out_specs=P(),
-                             check_rep=False)(view_states, spec_rows)
+            return jax.shard_map(sm_body, mesh=mesh,
+                                 in_specs=(state_spec, P()), out_specs=P(),
+                                 check_vma=False)(view_states, spec_rows)
     else:
         def program(view_states, spec_rows):
             return _batched_query_body(view_schema, cards, offsets,
@@ -884,8 +841,6 @@ def get_fused_rowlookup(codec, specs_items: Tuple, n_parts: int, mesh,
     ndev = 1 if mesh is None else int(mesh.shape[mesh_axis])
 
     if n_parts > 0 and ndev > 1:
-        from jax.experimental.shard_map import shard_map
-
         from repro.core.distributed import _routed_lookup_body
         body = functools.partial(_routed_lookup_body, codec=codec,
                                  specs=specs, n_parts=n_parts, n_dev=ndev,
@@ -895,11 +850,11 @@ def get_fused_rowlookup(codec, specs_items: Tuple, n_parts: int, mesh,
         def program(columns, valid, t_hi, t_lo, keep):
             n = valid.shape[0]
             pcols, pvalid = _pad_batch(columns, valid, ndev)
-            matched = shard_map(
+            matched = jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(P(mesh_axis), P(mesh_axis), part, part, part),
                 out_specs=P(mesh_axis),
-                check_rep=False)(pcols, pvalid, t_hi, t_lo, keep)
+                check_vma=False)(pcols, pvalid, t_hi, t_lo, keep)
             return matched[:n]
     else:
         from repro.core.coarsen import coarsen_columns
@@ -985,17 +940,16 @@ def get_fused_evict(tnames: Tuple[str, ...], caps: Tuple, n_parts: int,
         return new_state, counts, live_max
 
     if on_mesh:
-        from jax.experimental.shard_map import shard_map
         view_spec = P(mesh_axis, None) if n_parts else P()
         state_spec = dict(views=view_spec)
         if has_stream:
             state_spec["stream"] = P()
 
         def program(state, cutoff):
-            return shard_map(body, mesh=mesh,
-                             in_specs=(state_spec, P()),
-                             out_specs=(state_spec, P(), P()),
-                             check_rep=False)(state, cutoff)
+            return jax.shard_map(body, mesh=mesh,
+                                 in_specs=(state_spec, P()),
+                                 out_specs=(state_spec, P(), P()),
+                                 check_vma=False)(state, cutoff)
     else:
         program = body
 

@@ -76,7 +76,10 @@ def knn_quadratic(U_treated: jnp.ndarray, U_control: jnp.ndarray,
         run_d, run_i = carry
         Ub, vb, base = blk
         cn = jnp.sum(Ub * Ub, axis=1)[None, :]
-        dist = jnp.maximum(tn + cn - 2.0 * (U_treated @ Ub.T), 0.0)
+        # HIGHEST: the TPU's default f32 matmul rounds inputs to bf16 (see
+        # repro.core.distance)
+        dot = jnp.matmul(U_treated, Ub.T, precision=jax.lax.Precision.HIGHEST)
+        dist = jnp.maximum(tn + cn - 2.0 * dot, 0.0)
         dist = jnp.where(vb[None, :], dist, BIG)
         idx = (base + jnp.arange(block, dtype=jnp.int32))[None, :]
         idx = jnp.broadcast_to(idx, dist.shape)

@@ -49,9 +49,9 @@ under streaming INSERTs with work proportional to the DELTA, not the data:
      stamps, streaming-propensity update, verdict scalars) is one donated
      device program (:mod:`repro.core.fused`): state updates in place, the
      host fetches one verdict ``device_get`` and commits by reference
-     swap. Growth recompiles the program at a doubled capacity (keyed on
-     the granule count) and re-dispatches; only delta-capacity overflow
-     still falls back to the exact host rebuild. ``pipeline="planner"``
+     swap. Growth (a view or the delta table outgrowing its capacity)
+     recompiles the program at a doubled capacity (capacities step along
+     ``granule * 2**k``) and re-dispatches. ``pipeline="planner"``
      keeps the PR 3 two-dispatch planner path and ``pipeline="unfused"``
      the legacy per-merge-sync loop, both measurable in
      ``benchmarks/bench_online.py``.
@@ -65,7 +65,7 @@ under streaming INSERTs with work proportional to the DELTA, not the data:
      engine's canonical-reassembly memo is keyed on a state version
      bumped per commit. ``matched_rows`` is a one-dispatch
      routed row lookup on the partitioned layout. The canonical chunked
-     reduction (:func:`repro.kernels.segment_stats.chunked_sum`) makes
+     reduction (:func:`repro.kernels.segment_stats.canonical_sum`) makes
      every estimate a bitwise-deterministic function of the group content
      alone, so the fused path, the ``query_pipeline="assemble"``
      baseline and both engine layouts agree exactly.
@@ -114,7 +114,7 @@ from repro.core.cem import (CEMGroups, make_codec, overlap_keep, pack_keys,
 from repro.core.coarsen import CoarsenSpec
 from repro.core.propensity import (LogisticModel, StreamStats, design_matrix,
                                    fit_logistic)
-from repro.data.columnar import GrowableTable, Table, _round_capacity
+from repro.data.columnar import GrowableTable, Table
 from repro.launch.trace import counted_jit, device_fetch, record_batch
 
 import collections.abc as _cabc
@@ -125,13 +125,13 @@ __engine_owned__ = True
 
 BASE_VIEW = fused_mod.BASE_VIEW
 
-# The query reductions run at a fixed canonical chunk width
-# (repro.kernels.segment_stats.CANONICAL_BLOCK, via chunked_sum): the
-# key-sorted group stats reduce in fixed 1024-wide chunks combined
-# strictly sequentially, so estimates are a function of the canonical
-# group CONTENT alone — never of an engine's capacity, growth history or
-# partition count — and the same state yields bit-identical results from
-# every engine layout and query pipeline on any device count.
+# The query reductions run through repro.kernels.segment_stats.
+# canonical_sum: the key-sorted group stats reduce by a pairwise fold
+# whose association is fixed in the program, so estimates are a function
+# of the canonical group CONTENT alone — never of an engine's capacity,
+# growth history or partition count — and the same state yields
+# bit-identical results from every engine layout and query pipeline on
+# any device count.
 
 # Streamed batches are padded to power-of-two row buckets (floor below)
 # before they reach the compiled ingest pipeline: the fused program traces
@@ -149,6 +149,20 @@ def _bucket_rows(n: int) -> int:
     while b < n:
         b <<= 1
     return b
+
+
+def _capacity_ladder(n: int, granule: int) -> int:
+    """Smallest ``granule * 2**k`` holding ``n`` groups: the one rule by
+    which view and delta capacities grow, shrink and are restored. The
+    compiled ingest and query programs are keyed on capacities, so two
+    engines that reach the same group counts by different histories
+    (a live engine and one recovered from its checkpoint) land on the
+    same capacities and share programs; on the TPU compiler each program
+    that sorts 2^16 or more slots takes tens of seconds to build."""
+    cap = granule
+    while cap < n:
+        cap <<= 1
+    return cap
 
 
 def _bucket_specs(n: int) -> int:
@@ -328,26 +342,6 @@ class _PartView:
         self.pcub = tab
 
 
-def _run_fused_query(tab, keep: jnp.ndarray, treatment: str,
-                     subpopulation: SubPop, *, mesh=None,
-                     mesh_axis: str = "data",
-                     partitioned: bool = False) -> ATEEstimate:
-    """THE one construction of a fused query call: resolve the cached
-    program for (codec, treatment, frozen subpopulation, mesh layout),
-    select the stat columns the estimator consumes, dispatch once.
-    ``tab`` is any stat table with the cuboid field names — a replicated
-    ``Cuboid``, a ``(P, C)`` ``PartitionedCuboid``, or an assembled
-    canonical view — so every query pipeline and both engine layouts
-    share this single entry point."""
-    prog = fused_mod.get_fused_query(tab.codec, treatment,
-                                     _freeze_subpop(subpopulation),
-                                     mesh, mesh_axis, partitioned)
-    stats = {k: tab.stats[k]
-             for k in fused_mod.query_stat_names(treatment)}
-    return ATEEstimate(**prog(tab.key_hi, tab.key_lo, stats,
-                              tab.group_valid, keep))
-
-
 def _estimate_view(cub: cube_mod.Cuboid, keep: jnp.ndarray, treatment: str,
                    subpopulation: SubPop) -> ATEEstimate:
     """Causal estimate over one materialized view's stat table — ONE
@@ -366,7 +360,11 @@ def _estimate_view(cub: cube_mod.Cuboid, keep: jnp.ndarray, treatment: str,
     from the query path entirely; this shared body is also the
     ``query_pipeline="assemble"`` baseline and the differential oracle's
     estimator."""
-    return _run_fused_query(cub, keep, treatment, subpopulation)
+    prog = fused_mod.get_fused_query(cub.codec, treatment,
+                                     _freeze_subpop(subpopulation))
+    stats = {k: cub.stats[k] for k in fused_mod.query_stat_names(treatment)}
+    return ATEEstimate(**prog(cub.key_hi, cub.key_lo, stats,
+                              cub.group_valid, keep))
 
 
 # Touch-stamp helpers: the pure bodies live in ``repro.core.fused`` (the
@@ -900,27 +898,18 @@ class OnlineEngine:
             self.mesh_axis, self.use_pallas, retract, self._stream_names(),
             self.seed, donate)
 
-    def _fallback_overflow(self, batch: Table, retract: bool,
-                           orig: Table) -> DeltaReport:
-        """Delta-capacity overflow: the in-program delta table missed
-        groups. ``_delta_cap`` has already been grown; rebuild the delta
-        (now at the larger capacity) and take the exact legacy path."""
-        hi, lo, stats, gv, n_full, overflow = self._build_delta(batch)
-        return self._ingest_unfused(batch, hi, lo, stats, gv, n_full,
-                                    overflow, retract, orig=orig)
-
     def _grow_views(self, n_merged: Dict[str, int],
                     grew: Dict[str, bool]) -> None:
         """Capacity-doubling growth between fused dispatches: pad every
         overflowing view (invalid-key padding keeps tables sorted and
         binary-searchable) so the re-dispatched program — recompiled at the
-        new granule count — fits the merged table."""
+        new capacity — fits the merged table."""
         for name, g in grew.items():
             if not g:
                 continue
             tab = self._view_table(name)
-            new_cap = _round_capacity(max(n_merged[name], 2 * tab.capacity),
-                                      self.granule)
+            new_cap = _capacity_ladder(max(n_merged[name], 2 * tab.capacity),
+                                       self.granule)
             padded = cube_mod._pad_cuboid(tab, new_cap)
             pad = new_cap - tab.capacity
             if name == BASE_VIEW:
@@ -935,10 +924,12 @@ class OnlineEngine:
                        orig: Table = None) -> DeltaReport:
         """ONE compiled dispatch per steady-state batch: run the fused
         program (state donated), fetch the verdict scalars once, commit by
-        reference swap. Growth re-dispatches at a doubled capacity; only
-        delta overflow leaves the device-resident path. ``batch`` is the
-        bucket-padded table the program consumes; ``orig`` the caller's
-        batch, which row accounting reports."""
+        reference swap. A view that outgrew its capacity, or a delta with
+        more groups than the delta capacity, left the state untouched
+        (the program gates its commit): grow and re-dispatch at the larger
+        capacity. ``batch`` is the bucket-padded table the program
+        consumes; ``orig`` the caller's batch, which row accounting
+        reports."""
         orig = batch if orig is None else orig
         cols = {c: batch.columns[c] for c in self._row_cols}
         valid = batch.valid
@@ -956,10 +947,10 @@ class OnlineEngine:
             self._unpack_view_state(new_state)
             f = device_fetch(verdicts, label="ingest-verdict")
             if bool(f["overflow"]):
-                self._delta_cap = _round_capacity(
+                self._delta_cap = _capacity_ladder(
                     max(int(f["n_full"]), 2 * self._delta_cap),
                     self.delta_granule)
-                return self._fallback_overflow(batch, retract, orig)
+                continue
             if retract and (not all(map(bool, f["ok"].values()))
                             or f["neg_min"] < -0.5):
                 self._raise_bad_retraction()
@@ -1202,7 +1193,7 @@ class OnlineEngine:
         if fetched["overflow"]:
             # the sliced delta missed groups: fall back to the exact
             # host-compacted path and grow the delta capacity geometrically
-            self._delta_cap = _round_capacity(
+            self._delta_cap = _capacity_ladder(
                 max(int(n_full), 2 * self._delta_cap), self.delta_granule)
             return self._ingest_unfused(batch, hi, lo, stats, gv, n_full,
                                         overflow, retract, orig=orig)
@@ -1390,7 +1381,7 @@ class OnlineEngine:
         occupancy of a view falls below 1/4 of its (grown) capacity, a
         shrink pass slices the compacted tables down to a halved-or-
         smaller capacity and the next ingest recompiles at the smaller
-        granule count — long-lived streams whose live set collapses
+        capacity — long-lived streams whose live set collapses
         reclaim device memory (``state_bytes()`` decreases).
 
         Returns a LAZY :class:`EvictReport` ({view name: groups evicted}):
@@ -1474,7 +1465,7 @@ class OnlineEngine:
             gran = self._shrink_granule()
             if cap <= gran or 4 * live > cap:
                 continue
-            new_cap = max(gran, _round_capacity(max(2 * live, 1), gran))
+            new_cap = _capacity_ladder(2 * live, gran)
             if new_cap >= cap:
                 continue
             self._shrink_view(name, new_cap)
@@ -1494,20 +1485,24 @@ class OnlineEngine:
 
     def _fused_estimate(self, treatment: str,
                         subpopulation: SubPop) -> ATEEstimate:
-        """One-dispatch fused query over the RAW materialized state. The
-        replicated layout feeds the (C,) view arrays straight in; the
-        partitioned engine overrides this with the (P, C) state
-        (shard_map body on a mesh)."""
-        view = self.views[treatment]
-        return _run_fused_query(view.cuboid, view.keep, treatment,
-                                subpopulation)
+        """One-dispatch fused query over the RAW materialized state (both
+        layouts; shard_map body on a mesh), answered as a one-spec wave
+        of the batched query program. The spec is DATA, so one compiled
+        program per view schema and capacity serves every
+        subpopulation: a program with the predicate in its trace
+        compiles once per distinct subpopulation, and on the TPU
+        compiler a program that sorts 2^16 or more slots takes tens of
+        seconds to compile. Returns host scalars (one fetch inside)."""
+        return self._batched_estimate(
+            [self._normalize_spec((treatment, subpopulation))])[0]
 
     def _estimate(self, treatment: str, subpopulation: SubPop,
                   pipeline: str = None) -> ATEEstimate:
-        """Uncached estimate through the chosen query pipeline (device
-        scalars). Both pipelines share the canonical estimator body, so
-        they return bit-identical results — the differential harness
-        cross-checks them against the oracle on every stream."""
+        """Uncached estimate through the chosen query pipeline (host
+        scalars from "fused", device scalars from "assemble"). Both
+        pipelines share the canonical estimator body, so they return
+        bit-identical results — the differential harness cross-checks
+        them against the oracle on every stream."""
         pipeline = pipeline or self.query_pipeline
         if pipeline == "fused":
             return self._fused_estimate(treatment, subpopulation)
@@ -1540,17 +1535,19 @@ class OnlineEngine:
             return self._cache[key]
         self.cache_misses += 1
         est = self._estimate(treatment, subpopulation)
-        # THE one host sync of an uncached query: every scalar at once.
-        # state_version tags the committed MVCC snapshot this estimate
-        # was computed at (a cache hit keeps the version it was COMPUTED
-        # at — the entry surviving later commits means the delta
-        # predicate proved those commits did not touch it).
-        est = ATEEstimate(**device_fetch(dict(
-            ate=est.ate, att=est.att,
-            n_matched_treated=est.n_matched_treated,
-            n_matched_control=est.n_matched_control,
-            n_groups=est.n_groups, variance=est.variance),
-            label="query"), state_version=self._state_version)
+        # THE one host sync of an uncached query: every scalar at once
+        # (inside the batched path for "fused"). state_version tags the
+        # committed MVCC snapshot this estimate was computed at (a cache
+        # hit keeps the version it was COMPUTED at — the entry surviving
+        # later commits means the delta predicate proved those commits
+        # did not touch it).
+        if self.query_pipeline != "fused":
+            est = ATEEstimate(**device_fetch(dict(
+                ate=est.ate, att=est.att,
+                n_matched_treated=est.n_matched_treated,
+                n_matched_control=est.n_matched_control,
+                n_groups=est.n_groups, variance=est.variance),
+                label="query"), state_version=self._state_version)
         self._cache[key] = est
         return est
 
@@ -1954,12 +1951,13 @@ class OnlineEngine:
     def _install_view(self, name: str, v: dict) -> None:
         """Re-materialize one canonical view under the replicated layout:
         valid groups as a sorted prefix, invalid-key padding to the
-        granule-rounded capacity (the same convention empty/merged tables
-        use, so the next ingest merges against it transparently)."""
+        capacity the ladder gives (:func:`_capacity_ladder`, the rule live
+        growth follows, so a recovered engine reuses the live engine's
+        compiled programs)."""
         from repro.core.keys import INVALID_HI, INVALID_LO
         tab = self._view_table(name)
         n = int(np.asarray(v["hi"]).shape[0])
-        cap = _round_capacity(max(n, 1), self.granule)
+        cap = _capacity_ladder(n, self.granule)
         hi = np.full((cap,), INVALID_HI, np.uint32)
         lo = np.full((cap,), INVALID_LO, np.uint32)
         gv = np.zeros((cap,), bool)
@@ -2069,7 +2067,7 @@ class PartitionedOnlineEngine(OnlineEngine):
     (the differential test harness exercises this). All other arguments
     match :class:`OnlineEngine`; ``fused_host_sync=False`` /
     ``pipeline="unfused"`` are not supported (the partitioned path is
-    fused-only, with the exact host fallback on delta overflow).
+    fused-only).
     """
 
     def __init__(self, specs: Mapping[str, CoarsenSpec],
@@ -2187,7 +2185,7 @@ class PartitionedOnlineEngine(OnlineEngine):
         ONE fused verdict. ``pipeline="fused1"`` (default) does ALL of it —
         routing included — in one donated compiled dispatch; "planner"
         keeps the PR 3 two-dispatch path. Semantics (including the
-        retraction guard and the delta-overflow exact fallback) match
+        retraction guard and delta-overflow handling) match
         :meth:`OnlineEngine.ingest` bit for bit — including the
         ``overlap=True`` MVCC protocol (dispatch-only, lazy verdicts,
         commit-time rollback-and-replay)."""
@@ -2216,30 +2214,18 @@ class PartitionedOnlineEngine(OnlineEngine):
             self.n_parts, mesh, self.mesh_axis, self.use_pallas, retract,
             self._stream_names(), self.seed, donate)
 
-    def _fallback_overflow(self, batch: Table, retract: bool,
-                           orig: Table) -> DeltaReport:
-        """Exact host fallback on delta overflow: rebuild the delta at the
-        (already grown) capacity, re-route, run the planner commit path."""
-        tnames = tuple(sorted(self.treatments))
-        d = cube_mod.delta_cuboid(batch, self.specs, tnames, self.outcome,
-                                  granule=self._delta_cap)
-        deltas = self._route_from_base(d.key_hi, d.key_lo, dict(d.stats),
-                                       d.group_valid)
-        return self._ingest_parts(batch, deltas, jnp.asarray(0),
-                                  jnp.asarray(False), retract, orig=orig)
-
     def _grow_views(self, n_merged: Dict[str, int],
                     grew: Dict[str, bool]) -> None:
         """Per-partition capacity doubling: pad every (P, C) array of an
         overflowing view along the slot axis (keys stay sorted — invalid
         padding is the largest key) and let the next dispatch recompile at
-        the new per-partition granule count."""
+        the new per-partition capacity."""
         for name, g in grew.items():
             if not g:
                 continue
             tab = self._view_table(name)
-            new_cap = _round_capacity(max(n_merged[name], 2 * tab.capacity),
-                                      self._part_granule)
+            new_cap = _capacity_ladder(max(n_merged[name], 2 * tab.capacity),
+                                       self._part_granule)
             padded = self._place(cube_mod.pad_partitioned(tab, new_cap))
             pad = new_cap - tab.capacity
             if name == BASE_VIEW:
@@ -2276,7 +2262,7 @@ class PartitionedOnlineEngine(OnlineEngine):
         if fetched["overflow"]:
             # a routed table was truncated: rebuild the delta exactly on
             # the host, grow the capacity geometrically, and re-route
-            self._delta_cap = _round_capacity(
+            self._delta_cap = _capacity_ladder(
                 max(int(n_full), 2 * self._delta_cap), self.delta_granule)
             d = cube_mod.delta_cuboid(batch, self.specs, tnames,
                                       self.outcome,
@@ -2339,7 +2325,7 @@ class PartitionedOnlineEngine(OnlineEngine):
         a replicated checkpoint restores into ANY ``n_parts``), keep each
         partition's slice sorted (global key order restricted to one
         partition stays sorted — partition ids are monotone in the key),
-        and pad every partition to one shared granule-rounded capacity."""
+        and pad every partition to one shared capacity on the ladder."""
         from repro.core.keys import INVALID_HI, INVALID_LO
         tab = self._view_table(name)
         hi_c = np.asarray(v["hi"], np.uint32)
@@ -2352,8 +2338,7 @@ class PartitionedOnlineEngine(OnlineEngine):
         else:
             pid = np.zeros((0,), np.int64)
             counts = np.zeros((P,), np.int64)
-        cap = _round_capacity(max(int(counts.max()), 1),
-                              self._part_granule)
+        cap = _capacity_ladder(int(counts.max()), self._part_granule)
         hi = np.full((P, cap), INVALID_HI, np.uint32)
         lo = np.full((P, cap), INVALID_LO, np.uint32)
         gv = np.zeros((P, cap), bool)
@@ -2416,17 +2401,6 @@ class PartitionedOnlineEngine(OnlineEngine):
             entry = (self._state_version, cub, keep)
             self._assembled[treatment] = entry
         return entry[1], entry[2]
-
-    def _fused_estimate(self, treatment: str,
-                        subpopulation: SubPop) -> ATEEstimate:
-        """Fused one-dispatch query straight on the (P, C) partitioned
-        state: per-partition masking (sharded over the mesh when one is
-        attached — per-device work 1/N), canonical reduce in-program."""
-        pv = self.views[treatment]
-        mesh = self.mesh if self._mesh_ndev > 1 else None
-        return _run_fused_query(pv.pcub, pv.keep, treatment, subpopulation,
-                                mesh=mesh, mesh_axis=self.mesh_axis,
-                                partitioned=True)
 
     def _batch_query_flags(self) -> Tuple:
         """Batched queries run straight on the (P, C) partitioned state:

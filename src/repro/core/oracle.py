@@ -136,3 +136,62 @@ def logistic_oracle(X: np.ndarray, t: np.ndarray, valid: np.ndarray,
         H = (Xb * s[:, None]).T @ Xb + ridge * np.eye(Xb.shape[1])
         w -= np.linalg.solve(H, g)
     return 1 / (1 + np.exp(-(Xb @ w)))
+
+
+def cem_group_stats_oracle(buckets: Mapping[str, np.ndarray], t: np.ndarray,
+                           y: np.ndarray, valid: np.ndarray) -> Dict:
+    """Vectorized float64 twin of :func:`cem_oracle` for large row counts:
+    the CEM groups of the valid rows (one per distinct bucket tuple),
+    their treated/control counts and outcome sums, kept only where both
+    arms are present. Also reports the number of groups before and after
+    the overlap filter and the largest per-group sum of ``y**2`` (the
+    engine's f32 group stats are exact while it stays below 2^24 for
+    integer outcomes). ``tests/test_chip_smoke.py`` holds it equal to the
+    dict oracle."""
+    names = sorted(buckets)
+    v = np.asarray(valid, bool)
+    cols = [np.asarray(buckets[m])[v].astype(np.int64) for m in names]
+    key = np.zeros(int(v.sum()), np.int64)
+    for c in cols:
+        key = key * (int(c.max(initial=0)) + 1) + c
+    _, first, inv = np.unique(key, return_index=True, return_inverse=True)
+    g = len(first)
+    tt = np.asarray(t)[v].astype(np.float64)
+    yv = np.asarray(y)[v].astype(np.float64)
+    n = np.bincount(inv, minlength=g).astype(np.float64)
+    n_t = np.bincount(inv, weights=tt, minlength=g)
+    n_c = n - n_t
+    keep = (n_t > 0) & (n_c > 0)
+    return dict(
+        buckets={m: c[first][keep] for m, c in zip(names, cols)},
+        n_t=n_t[keep], n_c=n_c[keep],
+        sum_y_t=np.bincount(inv, weights=yv * tt, minlength=g)[keep],
+        sum_y_c=np.bincount(inv, weights=yv * (1 - tt), minlength=g)[keep],
+        n_groups_all=g, n_groups_matched=int(keep.sum()),
+        max_sum_yy=float(np.bincount(inv, weights=yv * yv,
+                                     minlength=g).max(initial=0.0)))
+
+
+def ate_att_oracle(groups: Mapping, subpopulation: Mapping = None) -> Dict:
+    """Eq. 4 ATE and the ATT over the matched groups of
+    :func:`cem_group_stats_oracle` whose buckets pass ``subpopulation``
+    (dim -> allowed buckets) — :func:`ate_oracle` / :func:`att_oracle`
+    over the same groups. ``scale_ate`` / ``scale_att`` are the
+    weight-averaged ``|mean_t| + |mean_c|``, the magnitude an f32
+    evaluation of the same sums rounds against."""
+    m = np.ones(len(groups["n_t"]), bool)
+    for dim, allowed in (subpopulation or {}).items():
+        m &= np.isin(groups["buckets"][dim], list(allowed))
+    n_t, n_c = groups["n_t"][m], groups["n_c"][m]
+    mean_t = groups["sum_y_t"][m] / np.maximum(n_t, 1)
+    mean_c = groups["sum_y_c"][m] / np.maximum(n_c, 1)
+    diff, mag = mean_t - mean_c, np.abs(mean_t) + np.abs(mean_c)
+    n_b = n_t + n_c
+
+    def wmean(w, x):
+        tot = w.sum()
+        return float((w * x).sum() / tot) if tot > 0 else 0.0
+    return dict(ate=wmean(n_b, diff), att=wmean(n_t, diff),
+                scale_ate=wmean(n_b, mag), scale_att=wmean(n_t, mag),
+                n_matched_treated=int(n_t.sum()),
+                n_matched_control=int(n_c.sum()), n_groups=int(m.sum()))

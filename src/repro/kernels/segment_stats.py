@@ -55,11 +55,11 @@ def _scatter_kernel(pos_ref, table_ref, vals_ref, out_ref):
     def _init():
         out_ref[...] = table_ref[...]
 
-    pos = pos_ref[...]                 # (B,) int32, in [0, C)
+    pos = pos_ref[0]                   # (1, B) int32, in [0, C)
     vals = vals_ref[...]               # (B, S) f32
     c = out_ref.shape[0]
-    b = pos.shape[0]
-    onehot = (pos[None, :] == jax.lax.broadcasted_iota(jnp.int32, (c, b), 0)
+    b = pos.shape[1]
+    onehot = (pos == jax.lax.broadcasted_iota(jnp.int32, (c, b), 0)
               ).astype(vals.dtype)     # (C, B): rows = destination slot
     out_ref[...] += jnp.dot(onehot, vals,
                             preferred_element_type=jnp.float32)
@@ -83,11 +83,14 @@ def scatter_merge_pallas(table: jnp.ndarray, pos: jnp.ndarray,
     """
     c, s = table.shape
     nb = pos.shape[0] // block
+    # positions ride as (nb, 1, block): a block whose last two dims equal
+    # the array's is legal on the chip at any nb, where a (block,) slice
+    # of a longer int32 vector does not match XLA's 1-D tiling
     return pl.pallas_call(
         _scatter_kernel,
         grid=(nb,),
         in_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),
+            pl.BlockSpec((1, 1, block), lambda i: (i, 0, 0)),
             pl.BlockSpec((c, s), lambda i: (0, 0)),
             pl.BlockSpec((block, s), lambda i: (i, 0)),
         ],
@@ -95,7 +98,7 @@ def scatter_merge_pallas(table: jnp.ndarray, pos: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((c, s), jnp.float32),
         input_output_aliases={1: 0},   # table (input 1) -> merged output
         interpret=interpret,
-    )(pos, table, vals)
+    )(pos.reshape(nb, 1, block), table, vals)
 
 
 def _scatter_parts_kernel(pos_ref, table_ref, vals_ref, out_ref):
@@ -105,11 +108,11 @@ def _scatter_parts_kernel(pos_ref, table_ref, vals_ref, out_ref):
     def _init():
         out_ref[...] = table_ref[...]
 
-    pos = pos_ref[0]                   # (B,) int32, in [0, C)
+    pos = pos_ref[0, 0]                # (1, B) int32, in [0, C)
     vals = vals_ref[0]                 # (B, S) f32
     c = out_ref.shape[1]
-    b = pos.shape[0]
-    onehot = (pos[None, :] == jax.lax.broadcasted_iota(jnp.int32, (c, b), 0)
+    b = pos.shape[1]
+    onehot = (pos == jax.lax.broadcasted_iota(jnp.int32, (c, b), 0)
               ).astype(vals.dtype)     # (C, B): rows = destination slot
     out_ref[0] += jnp.dot(onehot, vals,
                           preferred_element_type=jnp.float32)
@@ -133,8 +136,8 @@ def scatter_merge_parts_pallas(tables: jnp.ndarray, pos: jnp.ndarray,
     return pl.pallas_call(
         _scatter_parts_kernel,
         grid=(n_parts, nb),
-        in_specs=[
-            pl.BlockSpec((1, block), lambda p, j: (p, j)),
+        in_specs=[   # positions as (P, nb, 1, block), as in scatter_merge
+            pl.BlockSpec((1, 1, 1, block), lambda p, j: (p, j, 0, 0)),
             pl.BlockSpec((1, c, s), lambda p, j: (p, 0, 0)),
             pl.BlockSpec((1, block, s), lambda p, j: (p, j, 0)),
         ],
@@ -142,67 +145,37 @@ def scatter_merge_parts_pallas(tables: jnp.ndarray, pos: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((n_parts, c, s), jnp.float32),
         input_output_aliases={1: 0},   # table buffer updates in place
         interpret=interpret,
-    )(pos, tables, vals)
+    )(pos.reshape(n_parts, nb, 1, block), tables, vals)
 
 
-# canonical chunk width of the capacity-invariant query reductions — the
-# single source of truth for the device-resident query path's fixed
-# reduce window (historically the online engine's host-compaction
-# granule)
-CANONICAL_BLOCK = 1024
-
-
-def chunked_sum(x: jnp.ndarray, block: int = CANONICAL_BLOCK) -> jnp.ndarray:
+def canonical_sum(x: jnp.ndarray) -> jnp.ndarray:
     """Capacity-invariant canonical sum of a zero-tail-padded stat vector.
 
     The device-resident query pipeline reduces per-group statistics whose
     VALID content is a key-sorted prefix and whose tail is exact zeros —
     but whose total length depends on engine layout (view capacity,
-    partition count, growth history). A plain ``jnp.sum`` associates
-    differently per length, so the same groups could reduce to different
-    f32 bits on different engines. This sum is bitwise INVARIANT to
-    trailing zero padding: the vector is padded to a multiple of ``block``,
-    each ``block``-wide chunk is reduced with a fixed-shape ``jnp.sum``
-    (identical lowering for every chunk, in every program), and the chunk
-    partials are combined STRICTLY SEQUENTIALLY in order — appending zero
-    chunks appends exact ``+ 0.0`` steps, which cannot change the result.
-    Replicated / partitioned / assembled layouts therefore all reduce to
-    the same bits whenever their canonical key-sorted content matches.
+    partition count, growth history). A plain ``jnp.sum`` leaves the
+    association to the compiler, which picks it per shape, per layout and
+    per fusion (a vmapped spec batch reduces along a different tiled axis
+    than a single query), so the same groups could reduce to different
+    f32 bits. This sum fixes the association in the program itself: the
+    vector is zero-padded to a power of two and folded in halves,
+    ``x[:h] + x[h:]``, until one element is left — only elementwise
+    adds, whose order no backend may change. It is bitwise INVARIANT to
+    trailing zero padding: doubling the padded length first adds the all-
+    zero upper half onto the content (exact ``+ 0.0``), then folds the
+    same vector as before. Replicated / partitioned / assembled layouts,
+    single and batched queries therefore all reduce to the same bits
+    whenever their canonical key-sorted content matches.
     """
     n = x.shape[0]
-    pad = (-n) % block
-    if pad:
-        x = jnp.pad(x, (0, pad))
-    total = jnp.sum(x[:block])
-    for i in range(1, x.shape[0] // block):
-        total = total + jnp.sum(x[i * block:(i + 1) * block])
-    return total
-
-
-def _chunk_sums_kernel(vals_ref, out_ref):
-    out_ref[...] = jnp.sum(vals_ref[...], axis=0, keepdims=True)
-
-
-def chunk_sums_pallas(values: jnp.ndarray, block: int = CANONICAL_BLOCK,
-                      interpret: bool = True) -> jnp.ndarray:
-    """Per-chunk partial sums of a (N, S) stat bundle as ONE Pallas launch
-    over the chunk grid — the MXU/VPU hot path of the canonical query
-    reduction for very large group tables (N % block == 0). Returns
-    (nb, S) chunk partials; the caller combines them sequentially exactly
-    like :func:`chunked_sum`. The pure-jnp :func:`chunked_sum` is the
-    bit-exactness reference the query pipeline ships with; this kernel is
-    benchmarked/parity-tested (``tests/test_kernels.py``) for accelerator
-    deployments where the chunk reduce dominates."""
-    n, s = values.shape
-    nb = n // block
-    return pl.pallas_call(
-        _chunk_sums_kernel,
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((block, s), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, s), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nb, s), jnp.float32),
-        interpret=interpret,
-    )(values)
+    size = 1 << max(0, (n - 1).bit_length())
+    if size != n:
+        x = jnp.pad(x, (0, size - n))
+    while x.shape[0] > 1:
+        half = x.shape[0] // 2
+        x = x[:half] + x[half:]
+    return x[0]
 
 
 def combine_partials(partials: jnp.ndarray, block_base: jnp.ndarray,

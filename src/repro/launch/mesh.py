@@ -16,13 +16,11 @@ import jax
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    """`jax.make_mesh` across jax versions: `axis_types` (and
-    `jax.sharding.AxisType`) only exist in newer releases."""
-    try:
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    except (AttributeError, TypeError):
-        return jax.make_mesh(shape, axes)
+    """`jax.make_mesh` with every axis `Auto`: the engines place state with
+    explicit `NamedSharding`s and run collectives inside `jax.shard_map`
+    bodies, so no axis takes part in sharding-in-types."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_data_mesh(n_devices: int = None, axis: str = "data"):

@@ -117,11 +117,11 @@ def test_zql003_fires_on_order_sensitive_sum_in_estimator(tmp_path):
 def test_zql003_quiet_on_chunked_sum_and_exact_counts(tmp_path):
     assert _lint_snippet(tmp_path, OWNED + _D("""\
         import jax.numpy as jnp
-        from repro.kernels.segment_stats import chunked_sum
+        from repro.kernels.segment_stats import canonical_sum
 
         def estimate_view(y, m):
             n = jnp.sum(m.astype(jnp.int32))     # exact integer count
-            return chunked_sum(jnp.where(m, y, 0.0)), n
+            return canonical_sum(jnp.where(m, y, 0.0)), n
 
         def merge_tables(a, b):
             return jnp.sum(a) + jnp.sum(b)       # not an estimator

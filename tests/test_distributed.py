@@ -151,7 +151,6 @@ def test_distributed_newton_matches_single():
 
 def test_compressed_psum_close_to_exact():
     out = _run("""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.optim.grad_compress import compressed_psum_mean
 
@@ -161,8 +160,8 @@ def test_compressed_psum_close_to_exact():
     def body(x):
         return compressed_psum_mean(x[0], "data")[None]
 
-    f = jax.jit(shard_map(body, mesh=mesh, in_specs=(P("data", None),),
-                          out_specs=P(None), check_rep=False))
+    f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P("data", None),),
+                              out_specs=P(None), check_vma=False))
     got = np.asarray(f(jnp.asarray(g)))[0]
     want = g.mean(axis=0)
     err = np.linalg.norm(got - want) / np.linalg.norm(want)

@@ -175,20 +175,3 @@ def test_kernel_backed_cem_equals_engine():
     b = estimate_ate(engine.groups)
     np.testing.assert_allclose(float(a.ate), float(b.ate), rtol=1e-5)
 
-
-def test_chunk_sums_pallas_matches_chunked_sum():
-    # the MXU/VPU chunk-partials kernel of the canonical query reduction
-    # must agree with the pure-jnp bit-exactness reference
-    from repro.kernels.segment_stats import chunk_sums_pallas, chunked_sum
-    rng = np.random.default_rng(3)
-    n, s, block = 2048, 4, 256
-    vals = rng.normal(0, 1, (n, s)).astype(np.float32)
-    partials = np.asarray(chunk_sums_pallas(jnp.asarray(vals), block=block))
-    assert partials.shape == (n // block, s)
-    for j in range(s):
-        want = float(chunked_sum(jnp.asarray(vals[:, j]), block=block))
-        got = float(np.sum(partials[:, j].astype(np.float64)))
-        np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
-    # per-chunk partials match plain chunk sums exactly in f32
-    ref = vals.reshape(n // block, block, s).sum(axis=1)
-    np.testing.assert_allclose(partials, ref, rtol=1e-6, atol=1e-6)

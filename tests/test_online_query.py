@@ -372,13 +372,13 @@ def test_mesh_query_single_dispatch_and_routed_lookup_bit_identical():
 
 
 def test_chunked_sum_is_padding_invariant():
-    from repro.kernels.segment_stats import chunked_sum
+    from repro.kernels.segment_stats import canonical_sum
     import jax.numpy as jnp
     rng = np.random.default_rng(0)
     x = rng.normal(0, 1, 700).astype(np.float32)
-    a = float(chunked_sum(jnp.asarray(x)))
+    a = float(canonical_sum(jnp.asarray(x)))
     for pad in (0, 324, 1024, 3000):
-        b = float(chunked_sum(jnp.asarray(
+        b = float(canonical_sum(jnp.asarray(
             np.concatenate([x, np.zeros(pad, np.float32)]))))
         assert a == b, pad
     # and it agrees with plain sums to float tolerance
