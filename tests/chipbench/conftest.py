@@ -1,0 +1,8 @@
+"""Put the checkout's root on ``sys.path`` so that tests import the
+benchmark package ``chipbench``."""
+import pathlib
+import sys
+
+ROOT = pathlib.Path(__file__).resolve().parents[2]
+if str(ROOT) not in sys.path:
+    sys.path.insert(0, str(ROOT))
