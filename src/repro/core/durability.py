@@ -46,6 +46,7 @@ from repro.checkpoint import ckpt as ckpt_mod
 from repro.core import wal as wal_mod
 from repro.core.wal import KIND_EVICT, KIND_INGEST, KIND_RETRACT
 from repro.data.columnar import Table
+from repro.launch.trace import span
 
 #: contract-lint scoping: WAL-ordering rule ZQL008 applies here.
 __engine_owned__ = True
@@ -205,32 +206,38 @@ class DurableEngine:
         mode), then dispatch through the wrapped engine. The journal is
         written from host numpy column data BEFORE any device work, so
         overlap-mode steady state stays 1 dispatch / 0 host syncs."""
-        self._guard_degraded()
-        self.engine.validate_batch(batch, retract=retract)
-        cols = {c: np.asarray(batch.columns[c])
-                for c in self.engine._row_cols}
-        valid = np.asarray(batch.valid)
-        overlap = bool(getattr(self.engine, "overlap", False))
-        mark = self.wal.mark()
-        self._point("wal.pre-append")
-        self.wal.append_batch(KIND_RETRACT if retract else KIND_INGEST,
-                              cols, valid, sync=not overlap)
-        self._point("wal.post-append")
-        if overlap and (retract or len(self.engine._inflight)
-                        >= self.engine.max_inflight):
-            # the engine will hit an internal commit barrier inside this
-            # ingest: everything journaled so far must be on disk first
-            self.wal.sync()
-        try:
-            rep = self.engine.ingest(batch, retract=retract)
-        except ValueError:
-            # the engine rejected the operation eagerly (config guard,
-            # bad retraction) — its record must not survive, or replay
-            # would re-raise the same failure mid-recovery
-            self.wal.rollback(mark)
-            raise
-        self._point("ingest.post-dispatch")
-        return rep
+        with span("durable.ingest", rows=batch.nrows,
+                  retract=retract) as sp:
+            self._guard_degraded()
+            with span("engine.validate"):
+                self.engine.validate_batch(batch, retract=retract)
+            cols = {c: np.asarray(batch.columns[c])
+                    for c in self.engine._row_cols}
+            valid = np.asarray(batch.valid)
+            overlap = bool(getattr(self.engine, "overlap", False))
+            mark = self.wal.mark()
+            self._point("wal.pre-append")
+            seq = self.wal.append_batch(
+                KIND_RETRACT if retract else KIND_INGEST, cols, valid,
+                sync=not overlap)
+            sp.set(seq=seq)
+            self._point("wal.post-append")
+            if overlap and (retract or len(self.engine._inflight)
+                            >= self.engine.max_inflight):
+                # the engine will hit an internal commit barrier inside
+                # this ingest: everything journaled so far must be on disk
+                # first
+                self.wal.sync()
+            try:
+                rep = self.engine.ingest(batch, retract=retract)
+            except ValueError:
+                # the engine rejected the operation eagerly (config guard,
+                # bad retraction) — its record must not survive, or replay
+                # would re-raise the same failure mid-recovery
+                self.wal.rollback(mark)
+                raise
+            self._point("ingest.post-dispatch")
+            return rep
 
     def evict(self, ttl: int):
         self._guard_degraded()
@@ -243,11 +250,13 @@ class DurableEngine:
         """MVCC commit barrier: fsync the journal FIRST, then commit —
         no batch is ever acknowledged before its WAL record is durable
         (lint rule ZQL008 checks this ordering statically)."""
-        self.wal.sync()
-        self._point("commit.pre")
-        out = self.engine.commit()
-        self._point("commit.post")
-        return out
+        with span("durable.commit", seq=self.wal.last_seq):
+            self.wal.sync()
+            self._point("commit.pre")
+            with span("engine.commit"):
+                out = self.engine.commit()
+            self._point("commit.post")
+            return out
 
     # -------------------------------------------------------- checkpoint
     def checkpoint(self, wait: bool = False) -> int:

@@ -69,7 +69,7 @@ from repro.core.cem import overlap_keep, update_overlap
 from repro.core.keys import INVALID_HI, INVALID_LO
 from repro.core.propensity import _stream_retract, _stream_update
 from repro.kernels.segment_stats import canonical_sum
-from repro.launch.trace import counted_jit, hot_path
+from repro.launch.trace import counted_jit, hot_path, stage
 
 #: contract-lint scoping (tools/contract_check.py): this module is
 #: engine-owned — dispatch/donation rules ZQL001-ZQL006 apply.
@@ -126,23 +126,29 @@ def _merge_one_view(tname, st, d_hi, d_lo, d_stats, d_gv, counter,
     ``st`` is the view's state dict; ``tname`` is None for the base view
     (which carries no overlap mask). Returns (new_st, verdicts)."""
     cap = st["hi"].shape[0]
-    pos, found = groupby.lookup_rows_in_table(d_hi, d_lo, st["hi"], st["lo"])
-    ok = jnp.all(found | ~d_gv)
+    with stage("probe"):
+        pos, found = groupby.lookup_rows_in_table(d_hi, d_lo, st["hi"],
+                                                  st["lo"])
+        ok = jnp.all(found | ~d_gv)
     has_keep = st.get("keep") is not None
 
+    # each branch under the stage of its merge, so what the branch adds
+    # around its inner stages (copies of its outputs) is that stage's
+    @stage("merge_fast")
     def fast(_):
         mstats = cube_mod.scatter_merge_stats(st["stats"], pos, d_stats,
                                               use_pallas=use_pallas)
+        keep = None
         if has_keep:
-            nt = mstats[f"t_{tname}"]
-            keep = update_overlap(st["keep"], st["gv"], nt,
-                                  mstats["one"] - nt, pos)
-        else:
-            keep = None
+            with stage("overlap"):
+                nt = mstats[f"t_{tname}"]
+                keep = update_overlap(st["keep"], st["gv"], nt,
+                                      mstats["one"] - nt, pos)
         touch = stamp_touch(st["touch"], pos, d_gv, counter)
         return (st["hi"], st["lo"], mstats, st["gv"], keep, touch, pos,
                 jnp.int32(0))
 
+    @stage("resort")
     def slow(_):
         cat_hi = jnp.concatenate([st["hi"], d_hi])
         cat_lo = jnp.concatenate([st["lo"], d_lo])
@@ -151,25 +157,32 @@ def _merge_one_view(tname, st, d_hi, d_lo, d_stats, d_gv, counter,
             g, {k: jnp.concatenate([st["stats"][k], d_stats[k]])
                 for k in st["stats"]})
         n_merged = g.n_groups
-        nhi, nlo, ngv = g.group_hi[:cap], g.group_lo[:cap], g.group_valid[:cap]
+        nhi, nlo = g.group_hi[:cap], g.group_lo[:cap]
+        ngv = g.group_valid[:cap]
         nstats = {k: v[:cap] for k, v in sums.items()}
-        pos2, _ = groupby.lookup_rows_in_table(d_hi, d_lo, nhi, nlo)
+        with stage("relocate"):
+            pos2, _ = groupby.lookup_rows_in_table(d_hi, d_lo, nhi, nlo)
+        keep = None
         if has_keep:
-            nt = nstats[f"t_{tname}"]
-            keep = overlap_keep(ngv, nt, nstats["one"] - nt)
-        else:
-            keep = None
-        touch = stamp_touch(
-            remap_touch(st["hi"], st["lo"], st["gv"], nhi, nlo, st["touch"]),
-            pos2, d_gv, counter)
+            with stage("overlap"):
+                nt = nstats[f"t_{tname}"]
+                keep = overlap_keep(ngv, nt, nstats["one"] - nt)
+        with stage("touch_remap"):
+            moved = remap_touch(st["hi"], st["lo"], st["gv"], nhi, nlo,
+                                st["touch"])
+        with stage("relocate"):
+            touch = stamp_touch(moved, pos2, d_gv, counter)
         return nhi, nlo, nstats, ngv, keep, touch, pos2, n_merged
 
-    hi, lo, stats, gv, keep, touch, pos_out, n_merged = jax.lax.cond(
-        ok, fast, slow, None)
+    with stage("branch"):
+        hi, lo, stats, gv, keep, touch, pos_out, n_merged = jax.lax.cond(
+            ok, fast, slow, None)
     new_st = dict(hi=hi, lo=lo, stats=stats, gv=gv, touch=touch)
     if has_keep:
         new_st["keep"] = keep
-    return new_st, dict(ok=ok, grew=n_merged > cap, n_merged=n_merged,
+    with stage("gate"):
+        grew = n_merged > cap
+    return new_st, dict(ok=ok, grew=grew, n_merged=n_merged,
                         pos=pos_out, merged_stats=stats)
 
 
@@ -184,27 +197,31 @@ def _merge_one_view_parts(tname, st, d_hi, d_lo, d_stats, d_gv, counter,
     cond lifts outside the per-partition vmap and the untaken branch never
     executes."""
     cap = st["hi"].shape[1]
-    pos, found = jax.vmap(groupby.lookup_rows_in_table)(
-        d_hi, d_lo, st["hi"], st["lo"])
-    ok = jnp.all(found | ~d_gv)
-    if axis is not None:
-        ok = jax.lax.pmin(ok.astype(jnp.int32), axis) > 0
+    with stage("probe"):
+        pos, found = jax.vmap(groupby.lookup_rows_in_table)(
+            d_hi, d_lo, st["hi"], st["lo"])
+        ok = jnp.all(found | ~d_gv)
+        if axis is not None:
+            ok = jax.lax.pmin(ok.astype(jnp.int32), axis) > 0
     has_keep = st.get("keep") is not None
 
+    # each branch under the stage of its merge (see _merge_one_view)
+    @stage("merge_fast")
     def fast(_):
         mstats = cube_mod.scatter_merge_stats_parts(
             st["stats"], pos, d_stats, use_pallas=use_pallas)
+        keep = None
         if has_keep:
-            nt = mstats[f"t_{tname}"]
-            keep = jax.vmap(update_overlap)(st["keep"], st["gv"], nt,
-                                            mstats["one"] - nt, pos)
-        else:
-            keep = None
+            with stage("overlap"):
+                nt = mstats[f"t_{tname}"]
+                keep = jax.vmap(update_overlap)(st["keep"], st["gv"], nt,
+                                                mstats["one"] - nt, pos)
         touch = jax.vmap(stamp_touch, in_axes=(0, 0, 0, None))(
             st["touch"], pos, d_gv, counter)
         return (st["hi"], st["lo"], mstats, st["gv"], keep, touch, pos,
                 jnp.int32(0))
 
+    @stage("resort")
     def slow(_):
         def one(thi, tlo, tstats, tgv, dhi, dlo, dstats, dgv, tch):
             cat_hi = jnp.concatenate([thi, dhi])
@@ -215,31 +232,38 @@ def _merge_one_view_parts(tname, st, d_hi, d_lo, d_stats, d_gv, counter,
                     for k in tstats})
             nhi, nlo = g.group_hi[:cap], g.group_lo[:cap]
             nstats = {k: v[:cap] for k, v in sums.items()}
-            p2, _ = groupby.lookup_rows_in_table(dhi, dlo, nhi, nlo)
-            tch2 = stamp_touch(remap_touch(thi, tlo, tgv, nhi, nlo, tch),
-                               p2, dgv, counter)
+            with stage("relocate"):
+                p2, _ = groupby.lookup_rows_in_table(dhi, dlo, nhi, nlo)
+            with stage("touch_remap"):
+                moved = remap_touch(thi, tlo, tgv, nhi, nlo, tch)
+            with stage("relocate"):
+                tch2 = stamp_touch(moved, p2, dgv, counter)
             return (nhi, nlo, nstats, g.group_valid[:cap], tch2, p2,
                     g.n_groups)
 
         nhi, nlo, nstats, ngv, touch, pos2, nm = jax.vmap(one)(
             st["hi"], st["lo"], st["stats"], st["gv"], d_hi, d_lo, d_stats,
             d_gv, st["touch"])
+        keep = None
         if has_keep:
-            nt = nstats[f"t_{tname}"]
-            keep = jax.vmap(overlap_keep)(ngv, nt, nstats["one"] - nt)
-        else:
-            keep = None
+            with stage("overlap"):
+                nt = nstats[f"t_{tname}"]
+                keep = jax.vmap(overlap_keep)(ngv, nt, nstats["one"] - nt)
         return nhi, nlo, nstats, ngv, keep, touch, pos2, jnp.max(nm)
 
-    hi, lo, stats, gv, keep, touch, pos_out, n_merged = jax.lax.cond(
-        ok, fast, slow, None)
-    if axis is not None:
-        # cond branches hold no collectives; globalize the verdicts after
-        n_merged = jax.lax.pmax(n_merged, axis)
+    with stage("branch"):
+        hi, lo, stats, gv, keep, touch, pos_out, n_merged = jax.lax.cond(
+            ok, fast, slow, None)
+    with stage("gate"):
+        if axis is not None:
+            # cond branches hold no collectives; globalize the verdicts
+            # after
+            n_merged = jax.lax.pmax(n_merged, axis)
+        grew = n_merged > cap
     new_st = dict(hi=hi, lo=lo, stats=stats, gv=gv, touch=touch)
     if has_keep:
         new_st["keep"] = keep
-    return new_st, dict(ok=ok, grew=n_merged > cap, n_merged=n_merged,
+    return new_st, dict(ok=ok, grew=grew, n_merged=n_merged,
                         pos=pos_out, merged_stats=stats)
 
 
@@ -281,6 +305,11 @@ def _stream_step(stream, stream_names, columns, valid, retract, seed,
             stream_names, stream["res"], stream["pri"], stream["n"],
             stream["sums"], stream["sumsqs"], cols, valid, key)
     return dict(res=res, pri=pri, n=n, sums=sums, sumsqs=sumsqs)
+
+
+def _view_scope(name: str):
+    """``jax.named_scope`` around one view's stages of an ingest program."""
+    return jax.named_scope(f"view_{name}")
 
 
 def pad_tail(columns, valid, pad: int):
@@ -325,60 +354,69 @@ def get_fused_ingest(codec, specs_items, tnames: Tuple[str, ...],
     rollups = {name: dims for name, dims in view_dims if name != BASE_VIEW}
 
     def local_build(columns, valid):
-        hi, lo, sums, gv, n_groups = cube_mod.delta_build_body(
-            columns, valid, codec=codec, specs=specs, treatments=tnames,
-            outcome=outcome)
+        with stage("build"):
+            hi, lo, sums, gv, n_groups = cube_mod.delta_build_body(
+                columns, valid, codec=codec, specs=specs, treatments=tnames,
+                outcome=outcome)
         return hi, lo, sums, gv, n_groups, jnp.asarray(False)
 
     def merge_and_gate(delta, views, counter):
         """Everything after the delta build except the stream update —
         pure per-device local compute, shared verbatim by the 1-device and
         shard_map paths."""
-        hi, lo, stats, gv, n_full, overflow = delta
-        dcap = delta_cap
-        d_hi, d_lo, d_gv = hi[:dcap], lo[:dcap], gv[:dcap]
-        d_stats = {k: v[:dcap] for k, v in stats.items()}
-        overflow = overflow | (n_full > dcap)
-        if retract:
-            d_stats = {k: -v for k, v in d_stats.items()}
+        with stage("build"):
+            hi, lo, stats, gv, n_full, overflow = delta
+            dcap = delta_cap
+            d_hi, d_lo, d_gv = hi[:dcap], lo[:dcap], gv[:dcap]
+            d_stats = {k: v[:dcap] for k, v in stats.items()}
+            overflow = overflow | (n_full > dcap)
+            if retract:
+                d_stats = {k: -v for k, v in d_stats.items()}
         new_views, verdicts = {}, {}
         for name in (BASE_VIEW,) + tnames:
-            if name == BASE_VIEW:
-                v_hi, v_lo, v_stats, v_gv = d_hi, d_lo, d_stats, d_gv
-            else:
-                roll = cube_mod._rollup_fn(codec, rollups[name])
-                v_hi, v_lo, v_stats, v_gv = roll(d_hi, d_lo, d_gv, d_stats)
-            tname = None if name == BASE_VIEW else name
-            new_views[name], verdicts[name] = _merge_one_view(
-                tname, views[name], v_hi, v_lo, v_stats, v_gv,
-                counter, use_pallas)
-        all_ok = functools.reduce(
-            jnp.logical_and, [v["ok"] for v in verdicts.values()])
-        any_grew = functools.reduce(
-            jnp.logical_or, [v["grew"] for v in verdicts.values()])
-        neg = _neg_min(verdicts[BASE_VIEW]["merged_stats"], tnames)
-        commit = ~overflow & ~any_grew
-        if retract:
-            commit = commit & all_ok & (neg >= -0.5)
-        out = dict(
-            overflow=overflow, n_full=n_full, commit=commit, neg_min=neg,
-            ok={k: v["ok"] for k, v in verdicts.items()},
-            grew={k: v["grew"] for k, v in verdicts.items()},
-            n_merged={k: v["n_merged"] for k, v in verdicts.items()},
-            n_delta=jnp.sum(d_gv.astype(jnp.int32)),
-            gv=d_gv,
-            buckets={d: codec.extract(d_hi, d_lo, d) for d in codec.names})
-        return _gate(commit, new_views, views), out
+            with _view_scope(name):
+                if name == BASE_VIEW:
+                    v_hi, v_lo, v_stats, v_gv = d_hi, d_lo, d_stats, d_gv
+                else:
+                    with stage("rollup"):
+                        roll = cube_mod._rollup_fn(codec, rollups[name])
+                        v_hi, v_lo, v_stats, v_gv = roll(d_hi, d_lo, d_gv,
+                                                         d_stats)
+                tname = None if name == BASE_VIEW else name
+                new_views[name], verdicts[name] = _merge_one_view(
+                    tname, views[name], v_hi, v_lo, v_stats, v_gv,
+                    counter, use_pallas)
+        with stage("gate"):
+            all_ok = functools.reduce(
+                jnp.logical_and, [v["ok"] for v in verdicts.values()])
+            any_grew = functools.reduce(
+                jnp.logical_or, [v["grew"] for v in verdicts.values()])
+            neg = _neg_min(verdicts[BASE_VIEW]["merged_stats"], tnames)
+            commit = ~overflow & ~any_grew
+            if retract:
+                commit = commit & all_ok & (neg >= -0.5)
+            out = dict(
+                overflow=overflow, n_full=n_full, commit=commit,
+                neg_min=neg,
+                ok={k: v["ok"] for k, v in verdicts.items()},
+                grew={k: v["grew"] for k, v in verdicts.items()},
+                n_merged={k: v["n_merged"] for k, v in verdicts.items()},
+                n_delta=jnp.sum(d_gv.astype(jnp.int32)),
+                gv=d_gv,
+                buckets={d: codec.extract(d_hi, d_lo, d)
+                         for d in codec.names})
+            return _gate(commit, new_views, views), out
 
     def finish(new_views, out, state, columns, valid, n_batches):
         """Attach the stream update (full UNPADDED batch — reservoir
         priorities depend on the draw shape) gated by the commit scalar."""
         new_state = dict(views=new_views)
         if stream_names:
-            upd = _stream_step(state["stream"], stream_names, columns,
-                               valid, retract, seed, n_batches)
-            new_state["stream"] = _gate(out["commit"], upd,
-                                        state["stream"])
+            with stage("stream"):
+                upd = _stream_step(state["stream"], stream_names, columns,
+                                   valid, retract, seed, n_batches)
+                new_state["stream"] = _gate(out["commit"], upd,
+                                            state["stream"])
         return new_state, out
 
     if ndev > 1:
@@ -389,10 +427,13 @@ def get_fused_ingest(codec, specs_items, tnames: Tuple[str, ...],
                                   axis=mesh_axis)
 
         def body(columns, valid, views, counter):
-            return merge_and_gate(build(columns, valid), views, counter)
+            with stage("build"):
+                delta = build(columns, valid)
+            return merge_and_gate(delta, views, counter)
 
-        def program(columns, valid, state, counter, n_batches):
-            pcols, pvalid = _pad_batch(columns, valid, ndev)
+        def ingest_program(columns, valid, state, counter, n_batches):
+            with stage("build"):
+                pcols, pvalid = _pad_batch(columns, valid, ndev)
             new_views, out = jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(P(mesh_axis), P(mesh_axis), P(), P()),
@@ -400,12 +441,12 @@ def get_fused_ingest(codec, specs_items, tnames: Tuple[str, ...],
                 check_vma=False)(pcols, pvalid, state["views"], counter)
             return finish(new_views, out, state, columns, valid, n_batches)
     else:
-        def program(columns, valid, state, counter, n_batches):
+        def ingest_program(columns, valid, state, counter, n_batches):
             new_views, out = merge_and_gate(local_build(columns, valid),
                                             state["views"], counter)
             return finish(new_views, out, state, columns, valid, n_batches)
 
-    return counted_jit(program,
+    return counted_jit(ingest_program, label="ingest",
                        donate_argnums=(2,) if donate else ())
 
 
@@ -436,46 +477,51 @@ def get_fused_ingest_parts(codec, specs_items, tnames: Tuple[str, ...],
     def merge_and_gate(deltas, n_full, overflow, views, counter, axis):
         new_views, verdicts = {}, {}
         for name, _ in view_items:
-            d_hi, d_lo, d_stats, d_gv = deltas[name]
+            with _view_scope(name):
+                d_hi, d_lo, d_stats, d_gv = deltas[name]
+                if retract:
+                    with stage("build"):
+                        d_stats = {k: -v for k, v in d_stats.items()}
+                    deltas[name] = (d_hi, d_lo, d_stats, d_gv)
+                tname = None if name == BASE_VIEW else name
+                new_views[name], verdicts[name] = _merge_one_view_parts(
+                    tname, views[name], d_hi, d_lo, d_stats, d_gv,
+                    counter, use_pallas, axis=axis)
+        with stage("gate"):
+            all_ok = functools.reduce(
+                jnp.logical_and, [v["ok"] for v in verdicts.values()])
+            any_grew = functools.reduce(
+                jnp.logical_or, [v["grew"] for v in verdicts.values()])
+            neg = _neg_min(verdicts[BASE_VIEW]["merged_stats"], tnames,
+                           axis=axis)
+            commit = ~overflow & ~any_grew
             if retract:
-                d_stats = {k: -v for k, v in d_stats.items()}
-                deltas[name] = (d_hi, d_lo, d_stats, d_gv)
-            tname = None if name == BASE_VIEW else name
-            new_views[name], verdicts[name] = _merge_one_view_parts(
-                tname, views[name], d_hi, d_lo, d_stats, d_gv,
-                counter, use_pallas, axis=axis)
-        all_ok = functools.reduce(
-            jnp.logical_and, [v["ok"] for v in verdicts.values()])
-        any_grew = functools.reduce(
-            jnp.logical_or, [v["grew"] for v in verdicts.values()])
-        neg = _neg_min(verdicts[BASE_VIEW]["merged_stats"], tnames,
-                       axis=axis)
-        commit = ~overflow & ~any_grew
-        if retract:
-            commit = commit & all_ok & (neg >= -0.5)
-        b_gv = deltas[BASE_VIEW][3]
-        n_delta = jnp.sum(b_gv.astype(jnp.int32))
-        if axis is not None:
-            n_delta = jax.lax.psum(n_delta, axis)
-        out = dict(
-            overflow=overflow, n_full=n_full, commit=commit, neg_min=neg,
-            ok={k: v["ok"] for k, v in verdicts.items()},
-            grew={k: v["grew"] for k, v in verdicts.items()},
-            n_merged={k: v["n_merged"] for k, v in verdicts.items()},
-            n_delta=n_delta,
-            gv=b_gv,
-            buckets={d: codec.extract(deltas[BASE_VIEW][0],
-                                      deltas[BASE_VIEW][1], d)
-                     for d in codec.names})
-        return _gate(commit, new_views, views), out
+                commit = commit & all_ok & (neg >= -0.5)
+            b_gv = deltas[BASE_VIEW][3]
+            n_delta = jnp.sum(b_gv.astype(jnp.int32))
+            if axis is not None:
+                n_delta = jax.lax.psum(n_delta, axis)
+            out = dict(
+                overflow=overflow, n_full=n_full, commit=commit,
+                neg_min=neg,
+                ok={k: v["ok"] for k, v in verdicts.items()},
+                grew={k: v["grew"] for k, v in verdicts.items()},
+                n_merged={k: v["n_merged"] for k, v in verdicts.items()},
+                n_delta=n_delta,
+                gv=b_gv,
+                buckets={d: codec.extract(deltas[BASE_VIEW][0],
+                                          deltas[BASE_VIEW][1], d)
+                         for d in codec.names})
+            return _gate(commit, new_views, views), out
 
     def finish(new_views, out, state, columns, valid, n_batches):
         new_state = dict(views=new_views)
         if stream_names:
-            upd = _stream_step(state["stream"], stream_names, columns,
-                               valid, retract, seed, n_batches)
-            new_state["stream"] = _gate(out["commit"], upd,
-                                        state["stream"])
+            with stage("stream"):
+                upd = _stream_step(state["stream"], stream_names, columns,
+                                   valid, retract, seed, n_batches)
+                new_state["stream"] = _gate(out["commit"], upd,
+                                            state["stream"])
         return new_state, out
 
     if ndev > 1:
@@ -487,7 +533,8 @@ def get_fused_ingest_parts(codec, specs_items, tnames: Tuple[str, ...],
             axis=mesh_axis)
 
         def body(columns, valid, views, counter):
-            deltas, n_full, overflow = build(columns, valid)
+            with stage("build"):
+                deltas, n_full, overflow = build(columns, valid)
             return merge_and_gate(deltas, n_full, overflow, views, counter,
                                   mesh_axis)
 
@@ -496,8 +543,9 @@ def get_fused_ingest_parts(codec, specs_items, tnames: Tuple[str, ...],
                         ok=P(), grew=P(), n_merged=P(), n_delta=P(),
                         gv=part, buckets=part)
 
-        def program(columns, valid, state, counter, n_batches):
-            pcols, pvalid = _pad_batch(columns, valid, ndev)
+        def ingest_program(columns, valid, state, counter, n_batches):
+            with stage("build"):
+                pcols, pvalid = _pad_batch(columns, valid, ndev)
             new_views, out = jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(P(mesh_axis), P(mesh_axis), part, P()),
@@ -506,29 +554,32 @@ def get_fused_ingest_parts(codec, specs_items, tnames: Tuple[str, ...],
             return finish(new_views, out, state, columns, valid, n_batches)
     else:
         def single_build(columns, valid):
-            hi, lo, sums, gv, n_groups = cube_mod.delta_build_body(
-                columns, valid, codec=codec, specs=specs,
-                treatments=tnames, outcome=outcome)
-            dcap = delta_cap
-            b_hi, b_lo, b_gv = hi[:dcap], lo[:dcap], gv[:dcap]
-            b_stats = {k: v[:dcap] for k, v in sums.items()}
+            with stage("build"):
+                hi, lo, sums, gv, n_groups = cube_mod.delta_build_body(
+                    columns, valid, codec=codec, specs=specs,
+                    treatments=tnames, outcome=outcome)
+                dcap = delta_cap
+                b_hi, b_lo, b_gv = hi[:dcap], lo[:dcap], gv[:dcap]
+                b_stats = {k: v[:dcap] for k, v in sums.items()}
+                overflow = n_groups > dcap
             deltas = {}
             for name, dims in view_items:
-                if name == BASE_VIEW:
-                    v = (b_hi, b_lo, b_stats, b_gv)
-                else:
-                    roll = cube_mod._rollup_fn(codec, dims)
-                    v = roll(b_hi, b_lo, b_gv, b_stats)
-                deltas[name] = cube_mod.route_delta(*v, n_parts)
-            return deltas, n_groups, n_groups > dcap
+                with _view_scope(name), stage("rollup"):
+                    if name == BASE_VIEW:
+                        v = (b_hi, b_lo, b_stats, b_gv)
+                    else:
+                        roll = cube_mod._rollup_fn(codec, dims)
+                        v = roll(b_hi, b_lo, b_gv, b_stats)
+                    deltas[name] = cube_mod.route_delta(*v, n_parts)
+            return deltas, n_groups, overflow
 
-        def program(columns, valid, state, counter, n_batches):
+        def ingest_program(columns, valid, state, counter, n_batches):
             deltas, n_full, overflow = single_build(columns, valid)
             new_views, out = merge_and_gate(deltas, n_full, overflow,
                                             state["views"], counter, None)
             return finish(new_views, out, state, columns, valid, n_batches)
 
-    return counted_jit(program,
+    return counted_jit(ingest_program, label="ingest",
                        donate_argnums=(2,) if donate else ())
 
 
@@ -620,11 +671,11 @@ def get_fused_query(codec, treatment: str, subpop):
     cache key, so each distinct subpopulation compiles its own program;
     the default ``ate()`` path is the batched program below at B=1, whose
     spec is data."""
-    def program(hi, lo, stats, gv, keep):
+    def query_program(hi, lo, stats, gv, keep):
         return estimate_view_body(hi, lo, stats, gv, keep, codec=codec,
                                   treatment=treatment, subpop=subpop)
 
-    return counted_jit(program, label="query")
+    return counted_jit(query_program, label="query")
 
 
 # ===================== batched query: the spec table is DATA ================
@@ -811,16 +862,16 @@ def get_fused_query_batch(view_schema, cards, b_bucket: int, mesh,
             (part, part, (part,) * len(QUERY_ROLES), part, part)
             for _ in view_schema)
 
-        def program(view_states, spec_rows):
+        def query_batch_program(view_states, spec_rows):
             return jax.shard_map(sm_body, mesh=mesh,
                                  in_specs=(state_spec, P()), out_specs=P(),
                                  check_vma=False)(view_states, spec_rows)
     else:
-        def program(view_states, spec_rows):
+        def query_batch_program(view_states, spec_rows):
             return _batched_query_body(view_schema, cards, offsets,
                                        view_states, spec_rows)
 
-    return counted_jit(program, label="query")
+    return counted_jit(query_batch_program, label="query")
 
 
 @functools.lru_cache(maxsize=256)
@@ -847,7 +898,7 @@ def get_fused_rowlookup(codec, specs_items: Tuple, n_parts: int, mesh,
                                  axis=mesh_axis)
         part = P(mesh_axis, None)
 
-        def program(columns, valid, t_hi, t_lo, keep):
+        def rowlookup_program(columns, valid, t_hi, t_lo, keep):
             n = valid.shape[0]
             pcols, pvalid = _pad_batch(columns, valid, ndev)
             matched = jax.shard_map(
@@ -859,7 +910,7 @@ def get_fused_rowlookup(codec, specs_items: Tuple, n_parts: int, mesh,
     else:
         from repro.core.coarsen import coarsen_columns
 
-        def program(columns, valid, t_hi, t_lo, keep):
+        def rowlookup_program(columns, valid, t_hi, t_lo, keep):
             buckets = coarsen_columns(columns, specs)
             hi, lo = codec.pack(buckets, valid)
             if n_parts == 0:
@@ -871,7 +922,7 @@ def get_fused_rowlookup(codec, specs_items: Tuple, n_parts: int, mesh,
                                                       t_lo)
             return valid & found & keep[pid, pos]
 
-    return counted_jit(program, label="query")
+    return counted_jit(rowlookup_program, label="query")
 
 
 # ===================== device-resident eviction compaction ==================
@@ -945,16 +996,18 @@ def get_fused_evict(tnames: Tuple[str, ...], caps: Tuple, n_parts: int,
         if has_stream:
             state_spec["stream"] = P()
 
-        def program(state, cutoff):
+        def evict_program(state, cutoff):
             return jax.shard_map(body, mesh=mesh,
                                  in_specs=(state_spec, P()),
                                  out_specs=(state_spec, P(), P()),
                                  check_vma=False)(state, cutoff)
     else:
-        program = body
+        def evict_program(state, cutoff):
+            return body(state, cutoff)
 
     # keep_unused: the overlap keep mask is RECOMPUTED (not read) by the
     # body; without it jit would prune the donated input params and their
     # buffers could never alias the fresh keep outputs (donation must be
     # total — the jaxpr audit asserts every state leaf is consumed)
-    return counted_jit(program, donate_argnums=(0,), keep_unused=True)
+    return counted_jit(evict_program, donate_argnums=(0,),
+                       keep_unused=True)

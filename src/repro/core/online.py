@@ -115,7 +115,8 @@ from repro.core.coarsen import CoarsenSpec
 from repro.core.propensity import (LogisticModel, StreamStats, design_matrix,
                                    fit_logistic)
 from repro.data.columnar import GrowableTable, Table
-from repro.launch.trace import counted_jit, device_fetch, record_batch
+from repro.launch.trace import (count, counted_jit, device_fetch,
+                                record_batch, span)
 
 import collections.abc as _cabc
 
@@ -719,23 +720,32 @@ class OnlineEngine:
         the pipeline first (its guard must validate eagerly against
         committed state).
         """
-        self.validate_batch(batch, retract=retract)
-        self._resolve_evictions()
-        self._guard_retract_rows(retract)
-        if self.overlap and retract:
-            self.commit()
-        self._maybe_renorm_touch()
-        padded = self._bucket_pad(batch)
-        if self.pipeline == "fused1":
-            if self.overlap and not retract:
-                return self._ingest_overlap(padded, orig=batch)
-            return self._ingest_fused1(padded, retract, orig=batch)
+        with span("engine.ingest"):
+            with span("engine.validate"):
+                self.validate_batch(batch, retract=retract)
+            self._resolve_evictions()
+            self._guard_retract_rows(retract)
+            if self.overlap and retract:
+                self.commit()
+            self._maybe_renorm_touch()
+            with span("engine.pad"):
+                padded = self._bucket_pad(batch)
+            if self.pipeline == "fused1":
+                if self.overlap and not retract:
+                    return self._ingest_overlap(padded, orig=batch)
+                return self._ingest_fused1(padded, retract, orig=batch)
+            return self._ingest_staged(padded, retract, orig=batch)
+
+    def _ingest_staged(self, padded: Table, retract: bool,
+                       orig: Table) -> DeltaReport:
+        """The multi-dispatch baselines (``pipeline="planner"`` and
+        ``"unfused"``): build the delta, then merge it."""
         hi, lo, stats, gv, n_full, overflow = self._build_delta(padded)
         if self.pipeline == "planner":
             return self._ingest_fused(padded, hi, lo, stats, gv, n_full,
-                                      overflow, retract, orig=batch)
+                                      overflow, retract, orig=orig)
         return self._ingest_unfused(padded, hi, lo, stats, gv, n_full,
-                                    overflow, retract, orig=batch)
+                                    overflow, retract, orig=orig)
 
     def validate_batch(self, batch: Table, retract: bool = False) -> None:
         """Poison-batch quarantine: host-side schema/content validation of
@@ -938,45 +948,54 @@ class OnlineEngine:
         # guard treats jnp.asarray/implicit jit-arg transfers as implicit
         counter = jax.device_put(np.int32(self._ingest_count + 1))
         for _ in range(32):
-            prog = self._fused_program(retract)
-            n_batches = jax.device_put(
-                np.int32(0 if self.stream is None
-                         else self.stream.n_batches))
-            new_state, verdicts = prog(cols, valid, self._pack_view_state(),
-                                       counter, n_batches)
-            self._unpack_view_state(new_state)
-            f = device_fetch(verdicts, label="ingest-verdict")
+            with span("engine.dispatch"):
+                prog = self._fused_program(retract)
+                n_batches = jax.device_put(
+                    np.int32(0 if self.stream is None
+                             else self.stream.n_batches))
+                new_state, verdicts = prog(cols, valid,
+                                           self._pack_view_state(), counter,
+                                           n_batches)
+                self._unpack_view_state(new_state)
+            with span("engine.verdict_wait"):
+                f = device_fetch(verdicts, label="ingest-verdict")
             if bool(f["overflow"]):
-                self._delta_cap = _capacity_ladder(
-                    max(int(f["n_full"]), 2 * self._delta_cap),
-                    self.delta_granule)
+                with span("engine.grow"):
+                    self._delta_cap = _capacity_ladder(
+                        max(int(f["n_full"]), 2 * self._delta_cap),
+                        self.delta_granule)
+                count("ingest.redispatches")
                 continue
             if retract and (not all(map(bool, f["ok"].values()))
                             or f["neg_min"] < -0.5):
                 self._raise_bad_retraction()
             if not any(map(bool, f["grew"].values())):
                 break
-            self._grow_views({k: int(v) for k, v in f["n_merged"].items()},
-                             {k: bool(v) for k, v in f["grew"].items()})
+            with span("engine.grow"):
+                self._grow_views(
+                    {k: int(v) for k, v in f["n_merged"].items()},
+                    {k: bool(v) for k, v in f["grew"].items()})
+            count("ingest.redispatches")
         else:
             raise RuntimeError("fused ingest: capacity growth diverged")
         # committed on device; mirror the host-side bookkeeping
-        if self.rows is not None:
-            self.rows = self.rows.append(
-                orig.select(list(self.rows.table.columns)),
-                granule=self.row_granule)
-        if self.stream is not None:
-            self.stream = dataclasses.replace(
-                self.stream, n_batches=self.stream.n_batches + 1)
-        self.n_rows_ingested += -orig.nrows if retract else orig.nrows
-        self._ingest_count += 1
-        invalidated = self._invalidate(
-            np.asarray(f["gv"]).reshape(-1),
-            lambda d: np.asarray(f["buckets"][d]).reshape(-1))
-        return DeltaReport(n_rows=orig.nrows,
-                           n_delta_groups=int(f["n_delta"]),
-                           fast_path={k: bool(v) for k, v in f["ok"].items()},
-                           invalidated=invalidated)
+        with span("engine.bookkeep"):
+            if self.rows is not None:
+                self.rows = self.rows.append(
+                    orig.select(list(self.rows.table.columns)),
+                    granule=self.row_granule)
+            if self.stream is not None:
+                self.stream = dataclasses.replace(
+                    self.stream, n_batches=self.stream.n_batches + 1)
+            self.n_rows_ingested += -orig.nrows if retract else orig.nrows
+            self._ingest_count += 1
+            invalidated = self._invalidate(
+                np.asarray(f["gv"]).reshape(-1),
+                lambda d: np.asarray(f["buckets"][d]).reshape(-1))
+            return DeltaReport(
+                n_rows=orig.nrows, n_delta_groups=int(f["n_delta"]),
+                fast_path={k: bool(v) for k, v in f["ok"].items()},
+                invalidated=invalidated)
 
     # ------------------------------------------- MVCC overlap (pipelined)
     @staticmethod
@@ -1012,9 +1031,10 @@ class OnlineEngine:
             0 if self.stream is None else self.stream.n_batches + depth))
         src = (self._inflight[-1].state if depth
                else self._pack_view_state())
-        prog = self._fused_program(False, donate=depth > 0)
-        new_state, verdicts = prog(cols, valid, src, counter, n_batches)
-        self._start_async_fetch(verdicts)
+        with span("engine.dispatch"):
+            prog = self._fused_program(False, donate=depth > 0)
+            new_state, verdicts = prog(cols, valid, src, counter, n_batches)
+            self._start_async_fetch(verdicts)
         pending = PendingIngest(self, orig.nrows)
         self._inflight.append(_InFlight(state=new_state, verdicts=verdicts,
                                         batch=batch, orig=orig,
@@ -1040,8 +1060,9 @@ class OnlineEngine:
         if not entries:
             return []
         self._inflight = []
-        fetched = device_fetch([e.verdicts for e in entries],
-                               label="commit")
+        with span("engine.verdict_wait"):
+            fetched = device_fetch([e.verdicts for e in entries],
+                                   label="commit")
         n_good = 0
         for f in fetched:
             if bool(f["overflow"]) or any(map(bool, f["grew"].values())):
@@ -2068,6 +2089,15 @@ class PartitionedOnlineEngine(OnlineEngine):
     match :class:`OnlineEngine`; ``fused_host_sync=False`` /
     ``pipeline="unfused"`` are not supported (the partitioned path is
     fused-only).
+
+    Ingest (:meth:`OnlineEngine.ingest`) folds each batch into every
+    partitioned view: route the delta to owner partitions, merge, flip and
+    stamp per partition, fetch ONE fused verdict. ``pipeline="fused1"``
+    (default) does all of it, routing included, in one donated compiled
+    dispatch; "planner" keeps the two-dispatch path (:meth:`_ingest_staged`).
+    Semantics (the retraction guard, delta-overflow handling, the
+    ``overlap=True`` MVCC protocol) match the replicated engine's bit for
+    bit.
     """
 
     def __init__(self, specs: Mapping[str, CoarsenSpec],
@@ -2179,30 +2209,11 @@ class PartitionedOnlineEngine(OnlineEngine):
         return deltas, n_full, n_full > dcap
 
     # ------------------------------------------------------------- ingest
-    def ingest(self, batch: Table, retract: bool = False) -> DeltaReport:
-        """Fold one streamed batch into every partitioned view: route the
-        delta to owner partitions, merge/flip/stamp per partition, fetch
-        ONE fused verdict. ``pipeline="fused1"`` (default) does ALL of it —
-        routing included — in one donated compiled dispatch; "planner"
-        keeps the PR 3 two-dispatch path. Semantics (including the
-        retraction guard and delta-overflow handling) match
-        :meth:`OnlineEngine.ingest` bit for bit — including the
-        ``overlap=True`` MVCC protocol (dispatch-only, lazy verdicts,
-        commit-time rollback-and-replay)."""
-        self.validate_batch(batch, retract=retract)
-        self._resolve_evictions()
-        self._guard_retract_rows(retract)
-        if self.overlap and retract:
-            self.commit()
-        self._maybe_renorm_touch()
-        padded = self._bucket_pad(batch)
-        if self.pipeline == "fused1":
-            if self.overlap and not retract:
-                return self._ingest_overlap(padded, orig=batch)
-            return self._ingest_fused1(padded, retract, orig=batch)
+    def _ingest_staged(self, padded: Table, retract: bool,
+                       orig: Table) -> DeltaReport:
         deltas, n_full, overflow = self._build_delta_parts(padded)
         return self._ingest_parts(padded, deltas, n_full, overflow, retract,
-                                  orig=batch)
+                                  orig=orig)
 
     # --------------------------------------- single-dispatch (fused1) hooks
     def _fused_program(self, retract: bool, donate: bool = True):
@@ -2245,30 +2256,35 @@ class PartitionedOnlineEngine(OnlineEngine):
                       retract: bool, orig: Table = None) -> DeltaReport:
         orig = batch if orig is None else orig
         tnames = tuple(sorted(self.treatments))
-        plan = _plan_ingest_parts(
-            deltas, self.base.key_hi, self.base.key_lo, self.base.stats,
-            {t: self.views[t].pcub.key_hi for t in tnames},
-            {t: self.views[t].pcub.key_lo for t in tnames},
-            {t: self.views[t].pcub.stats for t in tnames},
-            {t: self.views[t].pcub.group_valid for t in tnames},
-            {t: self.views[t].keep for t in tnames},
-            codec=self.codec, tnames=tnames, retract=retract,
-            use_pallas=self.use_pallas)
+        with span("engine.dispatch"):
+            plan = _plan_ingest_parts(
+                deltas, self.base.key_hi, self.base.key_lo, self.base.stats,
+                {t: self.views[t].pcub.key_hi for t in tnames},
+                {t: self.views[t].pcub.key_lo for t in tnames},
+                {t: self.views[t].pcub.stats for t in tnames},
+                {t: self.views[t].pcub.group_valid for t in tnames},
+                {t: self.views[t].keep for t in tnames},
+                codec=self.codec, tnames=tnames, retract=retract,
+                use_pallas=self.use_pallas)
         # THE one host sync of a fast-path ingest
-        fetched = device_fetch(dict(
-            overflow=overflow, ok=plan["ok"], neg_min=plan["neg_min"],
-            n_delta=plan["n_delta"], gv=deltas[BASE_VIEW][3],
-            buckets=plan["buckets"]))
+        with span("engine.verdict_wait"):
+            fetched = device_fetch(dict(
+                overflow=overflow, ok=plan["ok"], neg_min=plan["neg_min"],
+                n_delta=plan["n_delta"], gv=deltas[BASE_VIEW][3],
+                buckets=plan["buckets"]))
         if fetched["overflow"]:
             # a routed table was truncated: rebuild the delta exactly on
             # the host, grow the capacity geometrically, and re-route
-            self._delta_cap = _capacity_ladder(
-                max(int(n_full), 2 * self._delta_cap), self.delta_granule)
-            d = cube_mod.delta_cuboid(batch, self.specs, tnames,
-                                      self.outcome,
-                                      granule=self._delta_cap)
-            deltas = self._route_from_base(d.key_hi, d.key_lo,
-                                           dict(d.stats), d.group_valid)
+            with span("engine.grow"):
+                self._delta_cap = _capacity_ladder(
+                    max(int(n_full), 2 * self._delta_cap),
+                    self.delta_granule)
+                d = cube_mod.delta_cuboid(batch, self.specs, tnames,
+                                          self.outcome,
+                                          granule=self._delta_cap)
+                deltas = self._route_from_base(d.key_hi, d.key_lo,
+                                               dict(d.stats), d.group_valid)
+            count("ingest.redispatches")
             return self._ingest_parts(batch, deltas, n_full,
                                       jnp.asarray(False), retract,
                                       orig=orig)
@@ -2276,8 +2292,24 @@ class PartitionedOnlineEngine(OnlineEngine):
         if retract and (not all_fast or fetched["neg_min"] < -0.5):
             self._raise_bad_retraction()
         counter = self._ingest_count + 1
+        with span("engine.dispatch"):
+            fast = self._merge_parts(deltas, plan, fetched, counter)
+        with span("engine.bookkeep"):
+            self._commit_rows(batch, retract, orig=orig)
+            self._post_state_swap()
+            invalidated = self._invalidate(
+                fetched["gv"].reshape(-1),
+                lambda d: fetched["buckets"][d].reshape(-1))
+            return DeltaReport(n_rows=orig.nrows,
+                               n_delta_groups=int(fetched["n_delta"]),
+                               fast_path=fast, invalidated=invalidated)
+
+    def _merge_parts(self, deltas, plan, fetched,
+                     counter: int) -> Dict[str, bool]:
+        """Per-view merges of the staged partitioned path, each on the
+        fast path the plan verdicts allow; returns view -> fast path."""
         fast: Dict[str, bool] = {}
-        for name in (BASE_VIEW, *tnames):
+        for name in (BASE_VIEW, *sorted(self.treatments)):
             ok = bool(fetched["ok"][name])
             d_hi, d_lo, d_stats, d_gv = deltas[name]
             pcub = (self.base if name == BASE_VIEW
@@ -2308,14 +2340,7 @@ class PartitionedOnlineEngine(OnlineEngine):
                                              merged.stats["one"] - nt)
                 view.pcub = merged
             fast[name] = ok
-        self._commit_rows(batch, retract, orig=orig)
-        self._post_state_swap()
-        invalidated = self._invalidate(
-            fetched["gv"].reshape(-1),
-            lambda d: fetched["buckets"][d].reshape(-1))
-        return DeltaReport(n_rows=orig.nrows,
-                           n_delta_groups=int(fetched["n_delta"]),
-                           fast_path=fast, invalidated=invalidated)
+        return fast
 
     # ------------------------------------------- durability (canonical)
     def _install_view(self, name: str, v: dict) -> None:

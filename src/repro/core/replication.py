@@ -63,7 +63,6 @@ from repro.core.durability import DurableEngine, _unpack_snapshot
 from repro.core.serving import ServedQuery, ServingEngine
 from repro.core.wal import StaleEpochError, TailCursor
 from repro.data.columnar import Table
-from repro.launch import trace
 from repro.runtime.fault_tolerance import HeartbeatMonitor
 
 #: contract-lint scoping: dispatch/WAL/ship-verify rules apply here.
@@ -216,14 +215,12 @@ class Replica:
             raise ReplicationError(f"replica {self.node_id} is down")
         if ship_epoch < self.epoch:
             self.n_stale_rejects += 1
-            trace.record_replication(stale_rejects=1)
             raise StaleEpochError(
                 f"ship at epoch {ship_epoch} rejected by replica "
                 f"{self.node_id} at epoch {self.epoch}")
         records, _, clean = wal_mod.decode_records(data)
         if not clean:
             self.n_torn_ships += 1
-            trace.record_replication(torn_ships=1)
         fresh = verify_records(records, max_epoch=ship_epoch,
                                after_seq=self.wal.last_seq)
         for rec in fresh:
@@ -262,7 +259,6 @@ class Replica:
             if done:
                 self.engine.commit()
                 self.n_applied += done
-                trace.record_replication(applied_records=done)
         return len(self._pending)
 
     def _apply_one(self, rec: wal_mod.Record) -> None:
@@ -551,8 +547,6 @@ class ReplicatedEngine:
             rep.primary_seq = last
             self.monitor.beat(nid, rep.wal.last_seq)
             total += n
-            trace.record_replication(ships=1, ship_records=n,
-                                     ship_bytes=len(data))
         return total
 
     def apply_all(self, n: Optional[int] = None) -> int:
@@ -666,7 +660,6 @@ class ReplicatedEngine:
                 r.epoch = max(r.epoch, new_epoch)
         self.monitor.beat(node_id, last)
         self.n_failovers += 1
-        trace.record_replication(failovers=1)
         return node_id
 
     # ----------------------------------------------------------- queries

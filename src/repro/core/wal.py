@@ -64,6 +64,8 @@ from typing import Dict, Iterable, List, Optional, Tuple
 
 import numpy as np
 
+from repro.launch.trace import count, span
+
 MAGIC = 0x5A51574C
 KIND_INGEST = 1
 KIND_RETRACT = 2
@@ -302,15 +304,24 @@ class BatchLog:
     def _append(self, kind: int, payload: bytes, sync: bool) -> int:
         self._check_fence(self.epoch)
         rec = Record(kind, self.last_seq + 1, payload, self.epoch)
+        n_bytes = self._write(rec, sync)
+        count("wal.records")
+        count("wal.bytes", n_bytes)
+        return rec.seq
+
+    def _write(self, rec: Record, sync: bool) -> int:
+        """Write ``rec`` at the end of the log; returns its size in
+        bytes."""
         fh = self._file()
-        fh.write(encode_record(rec))
+        data = encode_record(rec)
+        fh.write(data)
         fh.flush()
         self._dirty = True
         if sync:
             self.sync()
         self.last_seq = rec.seq
         self.last_epoch = rec.epoch
-        return rec.seq
+        return len(data)
 
     def append_batch(self, kind: int, columns: Dict[str, np.ndarray],
                      valid: np.ndarray, sync: bool = True) -> int:
@@ -318,7 +329,8 @@ class BatchLog:
         if kind not in (KIND_INGEST, KIND_RETRACT):
             raise ValueError(f"append_batch kind must be ingest/retract, "
                              f"got {kind}")
-        return self._append(kind, _encode_batch(columns, valid), sync)
+        with span("wal.append"):
+            return self._append(kind, _encode_batch(columns, valid), sync)
 
     def append_evict(self, ttl: int, sync: bool = True) -> int:
         return self._append(KIND_EVICT, json.dumps({"ttl": int(ttl)}).encode(),
@@ -339,14 +351,7 @@ class BatchLog:
             raise StaleEpochError(
                 f"shipped record epoch {rec.epoch} below log epoch "
                 f"{self.last_epoch} ({self.directory})")
-        fh = self._file()
-        fh.write(encode_record(rec))
-        fh.flush()
-        self._dirty = True
-        if sync:
-            self.sync()
-        self.last_seq = rec.seq
-        self.last_epoch = rec.epoch
+        self._write(rec, sync)
         return rec.seq
 
     def sync(self) -> None:
@@ -354,8 +359,10 @@ class BatchLog:
         appended since the last sync. Must complete before the commit
         barrier covering those records acknowledges (ZQL008)."""
         if self._fh is not None and self._dirty:
-            os.fsync(self._fh.fileno())
+            with span("wal.fsync"):
+                os.fsync(self._fh.fileno())
             self._dirty = False
+            count("wal.fsyncs")
 
     def mark(self) -> Tuple[int, bool, int]:
         """Position token for :meth:`rollback` — taken BEFORE an append
