@@ -108,11 +108,49 @@ def stamp_touch(touch: jnp.ndarray, pos: jnp.ndarray, dvalid: jnp.ndarray,
 @hot_path
 def remap_touch(old_hi, old_lo, old_gv, new_hi, new_lo,
                 touch: jnp.ndarray) -> jnp.ndarray:
-    """Carry last-touch stamps across a layout-changing (re-sort) merge."""
+    """Carry last-touch stamps across a layout-changing (re-sort) merge by
+    binary-searching every old key in the new table. Only the planner and
+    unfused pipelines use it (``online._remap_touch*``): they have no
+    grouping of the merge at hand. The fused program reads each row's new
+    slot off the merge's own grouping instead (:func:`_resort_merge`)."""
     pos, found = groupby.lookup_rows_in_table(old_hi, old_lo, new_hi, new_lo)
     upd = jnp.where(old_gv & found, pos, new_hi.shape[0])
     return jnp.zeros((new_hi.shape[0],), touch.dtype).at[upd].set(
         touch, mode="drop")
+
+
+@hot_path
+def _resort_merge(hi, lo, stats, gv, touch, d_hi, d_lo, d_stats, d_gv,
+                  counter):
+    """Re-sort merge of one sorted table (a replicated view, or one
+    partition of a partitioned view) with a delta, at the table's
+    capacity: group ``[table; delta]`` by key and sum the stats per group.
+    Returns (hi, lo, stats, gv, touch, n_groups); ``n_groups`` above the
+    capacity is a merge that does not fit.
+
+    The touch stamps come from the grouping itself: ``g.perm`` and
+    ``g.seg_ids`` already say which new slot every table and delta row
+    went to, so no key is searched. The table's stamps move to their
+    rows' slots, then the delta's slots take ``counter``. Each of the two
+    scatters writes distinct slots (the valid keys of a table, and of a
+    delta, are distinct), so the result is exact. A slot past the
+    capacity (a merge that grew, whose state is not committed) is
+    dropped."""
+    cap = hi.shape[0]
+    g = groupby.group_by_key(jnp.concatenate([hi, d_hi]),
+                             jnp.concatenate([lo, d_lo]))
+    sums = groupby.segment_sums(
+        g, {k: jnp.concatenate([stats[k], d_stats[k]]) for k in stats})
+    with stage("touch_remap"):
+        slot = jnp.zeros(g.perm.shape, jnp.int32).at[g.perm].set(
+            g.seg_ids, unique_indices=True)
+        upd = jnp.where(gv, slot[:cap], cap)
+        moved = jnp.zeros_like(touch).at[upd].set(touch, mode="drop")
+    with stage("relocate"):
+        touch = stamp_touch(moved, slot[cap:], d_gv, counter)
+    return (g.group_hi[:cap], g.group_lo[:cap],
+            {k: v[:cap] for k, v in sums.items()}, g.group_valid[:cap],
+            touch, g.n_groups)
 
 
 # ----------------------------------------------------------- merge kernels
@@ -145,37 +183,22 @@ def _merge_one_view(tname, st, d_hi, d_lo, d_stats, d_gv, counter,
                 keep = update_overlap(st["keep"], st["gv"], nt,
                                       mstats["one"] - nt, pos)
         touch = stamp_touch(st["touch"], pos, d_gv, counter)
-        return (st["hi"], st["lo"], mstats, st["gv"], keep, touch, pos,
-                jnp.int32(0))
+        return st["hi"], st["lo"], mstats, st["gv"], keep, touch, jnp.int32(0)
 
     @stage("resort")
     def slow(_):
-        cat_hi = jnp.concatenate([st["hi"], d_hi])
-        cat_lo = jnp.concatenate([st["lo"], d_lo])
-        g = groupby.group_by_key(cat_hi, cat_lo)
-        sums = groupby.segment_sums(
-            g, {k: jnp.concatenate([st["stats"][k], d_stats[k]])
-                for k in st["stats"]})
-        n_merged = g.n_groups
-        nhi, nlo = g.group_hi[:cap], g.group_lo[:cap]
-        ngv = g.group_valid[:cap]
-        nstats = {k: v[:cap] for k, v in sums.items()}
-        with stage("relocate"):
-            pos2, _ = groupby.lookup_rows_in_table(d_hi, d_lo, nhi, nlo)
+        nhi, nlo, nstats, ngv, touch, n_merged = _resort_merge(
+            st["hi"], st["lo"], st["stats"], st["gv"], st["touch"], d_hi,
+            d_lo, d_stats, d_gv, counter)
         keep = None
         if has_keep:
             with stage("overlap"):
                 nt = nstats[f"t_{tname}"]
                 keep = overlap_keep(ngv, nt, nstats["one"] - nt)
-        with stage("touch_remap"):
-            moved = remap_touch(st["hi"], st["lo"], st["gv"], nhi, nlo,
-                                st["touch"])
-        with stage("relocate"):
-            touch = stamp_touch(moved, pos2, d_gv, counter)
-        return nhi, nlo, nstats, ngv, keep, touch, pos2, n_merged
+        return nhi, nlo, nstats, ngv, keep, touch, n_merged
 
     with stage("branch"):
-        hi, lo, stats, gv, keep, touch, pos_out, n_merged = jax.lax.cond(
+        hi, lo, stats, gv, keep, touch, n_merged = jax.lax.cond(
             ok, fast, slow, None)
     new_st = dict(hi=hi, lo=lo, stats=stats, gv=gv, touch=touch)
     if has_keep:
@@ -183,7 +206,7 @@ def _merge_one_view(tname, st, d_hi, d_lo, d_stats, d_gv, counter,
     with stage("gate"):
         grew = n_merged > cap
     return new_st, dict(ok=ok, grew=grew, n_merged=n_merged,
-                        pos=pos_out, merged_stats=stats)
+                        merged_stats=stats)
 
 
 @hot_path
@@ -218,41 +241,23 @@ def _merge_one_view_parts(tname, st, d_hi, d_lo, d_stats, d_gv, counter,
                                                 mstats["one"] - nt, pos)
         touch = jax.vmap(stamp_touch, in_axes=(0, 0, 0, None))(
             st["touch"], pos, d_gv, counter)
-        return (st["hi"], st["lo"], mstats, st["gv"], keep, touch, pos,
-                jnp.int32(0))
+        return st["hi"], st["lo"], mstats, st["gv"], keep, touch, jnp.int32(0)
 
     @stage("resort")
     def slow(_):
-        def one(thi, tlo, tstats, tgv, dhi, dlo, dstats, dgv, tch):
-            cat_hi = jnp.concatenate([thi, dhi])
-            cat_lo = jnp.concatenate([tlo, dlo])
-            g = groupby.group_by_key(cat_hi, cat_lo)
-            sums = groupby.segment_sums(
-                g, {k: jnp.concatenate([tstats[k], dstats[k]])
-                    for k in tstats})
-            nhi, nlo = g.group_hi[:cap], g.group_lo[:cap]
-            nstats = {k: v[:cap] for k, v in sums.items()}
-            with stage("relocate"):
-                p2, _ = groupby.lookup_rows_in_table(dhi, dlo, nhi, nlo)
-            with stage("touch_remap"):
-                moved = remap_touch(thi, tlo, tgv, nhi, nlo, tch)
-            with stage("relocate"):
-                tch2 = stamp_touch(moved, p2, dgv, counter)
-            return (nhi, nlo, nstats, g.group_valid[:cap], tch2, p2,
-                    g.n_groups)
-
-        nhi, nlo, nstats, ngv, touch, pos2, nm = jax.vmap(one)(
-            st["hi"], st["lo"], st["stats"], st["gv"], d_hi, d_lo, d_stats,
-            d_gv, st["touch"])
+        nhi, nlo, nstats, ngv, touch, nm = jax.vmap(
+            _resort_merge, in_axes=(0,) * 9 + (None,))(
+                st["hi"], st["lo"], st["stats"], st["gv"], st["touch"], d_hi,
+                d_lo, d_stats, d_gv, counter)
         keep = None
         if has_keep:
             with stage("overlap"):
                 nt = nstats[f"t_{tname}"]
                 keep = jax.vmap(overlap_keep)(ngv, nt, nstats["one"] - nt)
-        return nhi, nlo, nstats, ngv, keep, touch, pos2, jnp.max(nm)
+        return nhi, nlo, nstats, ngv, keep, touch, jnp.max(nm)
 
     with stage("branch"):
-        hi, lo, stats, gv, keep, touch, pos_out, n_merged = jax.lax.cond(
+        hi, lo, stats, gv, keep, touch, n_merged = jax.lax.cond(
             ok, fast, slow, None)
     with stage("gate"):
         if axis is not None:
@@ -264,7 +269,7 @@ def _merge_one_view_parts(tname, st, d_hi, d_lo, d_stats, d_gv, counter,
     if has_keep:
         new_st["keep"] = keep
     return new_st, dict(ok=ok, grew=grew, n_merged=n_merged,
-                        pos=pos_out, merged_stats=stats)
+                        merged_stats=stats)
 
 
 @hot_path

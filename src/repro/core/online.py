@@ -978,6 +978,8 @@ class OnlineEngine:
             count("ingest.redispatches")
         else:
             raise RuntimeError("fused ingest: capacity growth diverged")
+        count("ingest.resort_merges",
+              sum(not bool(v) for v in f["ok"].values()))
         # committed on device; mirror the host-side bookkeeping
         with span("engine.bookkeep"):
             if self.rows is not None:
@@ -1084,6 +1086,8 @@ class OnlineEngine:
                 self.stream, n_batches=self.stream.n_batches + len(entries))
         reports = []
         for e, f in zip(entries, fetched):
+            count("ingest.resort_merges",
+                  sum(not bool(v) for v in f["ok"].values()))
             if self.rows is not None:
                 self.rows = self.rows.append(
                     e.orig.select(list(self.rows.table.columns)),

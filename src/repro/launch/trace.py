@@ -38,8 +38,10 @@ Counters of the recorder (:func:`count`, :func:`counters`) are kept
 on the same terms, where the work happens: ``wal.records`` and
 ``wal.bytes`` (records a log appended itself), ``wal.fsyncs``,
 ``ingest.redispatches`` (growth or delta-overflow re-dispatches of one
-batch). Compile seconds (:func:`compile_seconds`) come from a
-``jax.monitoring`` listener registered at import.
+batch), ``ingest.resort_merges`` (views of a committed batch that took
+the re-sort branch of the fused ingest program). Compile seconds
+(:func:`compile_seconds`) come from a ``jax.monitoring`` listener
+registered at import.
 
 The fused ingest program names its stages with :func:`stage`
 (``jax.named_scope``, one of :data:`INGEST_STAGES`);

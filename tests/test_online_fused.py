@@ -20,6 +20,10 @@ Contracts under test:
   * K-PARTITIONS-PER-DEVICE — ``n_parts`` may exceed the device count;
     hash-skewed streams keep every partition's occupancy under capacity.
   * the fused Pallas scatter-merge-parts kernel matches the vmapped oracle.
+  * THE RE-SORT MERGE'S SLOT MAP IS EXACT — touch stamps carried by the
+    grouping's own permutation equal, bit for bit, those found by binary
+    search of every old and delta key, and batches that do not commit
+    pass the state through unchanged.
 """
 import os
 import subprocess
@@ -32,7 +36,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core import CoarsenSpec, OnlineEngine, PartitionedOnlineEngine
-from repro.core import cube, fused
+from repro.core import cube, fused, groupby
 from repro.core.online import BASE_VIEW
 from repro.data.columnar import Table
 from repro.launch.trace import count_dispatches
@@ -332,6 +336,123 @@ def test_use_pallas_fused_ingest_matches_default():
     for t in TREATMENTS:
         assert float(a.ate(t).ate) == float(b.ate(t).ate)
         assert float(a.ate(t).ate) == float(pa.ate(t).ate)
+
+
+# ------------------------------------- re-sort slot map vs key search ----
+WIDE = {"x0": CoarsenSpec.categorical(16), "x1": CoarsenSpec.categorical(8),
+        "x2": CoarsenSpec.categorical(5)}
+
+
+def _wide_batch(n, seed, x0_hi):
+    rng = np.random.default_rng(seed)
+    cols = {"x0": rng.integers(0, x0_hi, n).astype(np.int32),
+            "x1": rng.integers(0, 8, n).astype(np.int32),
+            "x2": rng.integers(0, 5, n).astype(np.int32),
+            "ta": (rng.random(n) < 0.5).astype(np.int32),
+            "tb": (rng.random(n) < 0.3).astype(np.int32)}
+    cols["y"] = np.round(rng.normal(0, 2, n)).astype(np.float32)
+    return Table.from_numpy(cols, rng.random(n) > 0.1)
+
+
+# new keys, re-touched and untouched old keys, invalid rows and padded
+# slots; the fourth batch outgrows both the views' and the delta's
+# capacity, so its first dispatches pass the state through
+SLOT_FEED = [(200, 1, 2), (150, 2, 3), (120, 3, 3), (400, 4, 16),
+             (100, 5, 16)]
+
+
+def _resort_merge_by_search(hi, lo, stats, gv, touch, d_hi, d_lo, d_stats,
+                            d_gv, counter):
+    """Reference re-sort merge that finds slots by key search: the new
+    slot of every old key and every delta key binary-searched in the
+    merged table (``remap_touch``, ``lookup_rows_in_table``)."""
+    cap = hi.shape[0]
+    g = groupby.group_by_key(jnp.concatenate([hi, d_hi]),
+                             jnp.concatenate([lo, d_lo]))
+    sums = groupby.segment_sums(
+        g, {k: jnp.concatenate([stats[k], d_stats[k]]) for k in stats})
+    nhi, nlo = g.group_hi[:cap], g.group_lo[:cap]
+    pos, _ = groupby.lookup_rows_in_table(d_hi, d_lo, nhi, nlo)
+    moved = fused.remap_touch(hi, lo, gv, nhi, nlo, touch)
+    return (nhi, nlo, {k: v[:cap] for k, v in sums.items()},
+            g.group_valid[:cap], fused.stamp_touch(moved, pos, d_gv, counter),
+            g.n_groups)
+
+
+def _clear_ingest_programs():
+    fused.get_fused_ingest.cache_clear()
+    fused.get_fused_ingest_parts.cache_clear()
+
+
+def _assert_bit_identical(got, want):
+    a = jax.tree_util.tree_leaves_with_path(got)
+    b = jax.tree_util.tree_leaves_with_path(want)
+    assert [p for p, _ in a] == [p for p, _ in b]
+    for (path, x), (_, y) in zip(a, b):
+        x, y = np.asarray(x), np.asarray(y)
+        assert x.dtype == y.dtype and x.shape == y.shape, path
+        assert x.tobytes() == y.tobytes(), path
+
+
+def _uncommitted_dispatch(eng, batch):
+    """Run the engine's ingest program on ``batch`` without donating or
+    committing: (commit verdict, output views, input views)."""
+    padded = eng._bucket_pad(batch)
+    before = jax.device_get(eng._pack_view_state()["views"])
+    state, verdicts = eng._fused_program(False, donate=False)(
+        {c: padded.columns[c] for c in eng._row_cols}, padded.valid,
+        eng._pack_view_state(), jnp.int32(eng._ingest_count + 1),
+        jnp.int32(eng.stream.n_batches))
+    return bool(verdicts["commit"]), jax.device_get(state["views"]), before
+
+
+@pytest.mark.parametrize("make", [
+    lambda: OnlineEngine(WIDE, TREATMENTS, "y", granule=64,
+                         delta_granule=128),
+    lambda: PartitionedOnlineEngine(WIDE, TREATMENTS, "y", granule=64,
+                                    delta_granule=128, n_parts=2),
+], ids=["replicated", "partitioned"])
+def test_resort_slot_map_matches_key_search_bit_for_bit(make, monkeypatch):
+    """Committed keys, stats, group flags and touch stamps of every view,
+    after every batch, equal those of the same program whose re-sort
+    merge binary-searches each key, bit for bit."""
+    feed = [_wide_batch(*args) for args in SLOT_FEED]
+
+    def run(check_pass_through):
+        _clear_ingest_programs()
+        eng = make()
+        states, held = [], 0
+        for b in feed:
+            if check_pass_through:
+                commit, out, before = _uncommitted_dispatch(eng, b)
+                if not commit:
+                    held += 1
+                    _assert_bit_identical(out, before)
+            rep = eng.ingest(b)
+            assert not all(rep.fast_path.values())
+            states.append(jax.device_get(eng._pack_view_state()["views"]))
+        return states, held
+
+    try:
+        got, held = run(check_pass_through=True)
+        traced = []
+
+        def by_search(*args):
+            traced.append(True)
+            return _resort_merge_by_search(*args)
+
+        with monkeypatch.context() as m:
+            m.setattr(fused, "_resort_merge", by_search)
+            want, _ = run(check_pass_through=False)
+    finally:
+        _clear_ingest_programs()
+    assert traced and held >= 1
+    assert got[-1][BASE_VIEW]["hi"].shape[-1] > got[0][BASE_VIEW][
+        "hi"].shape[-1]                                # a capacity grew
+    touch = got[-1][BASE_VIEW]["touch"]
+    assert len(np.unique(touch[got[-1][BASE_VIEW]["gv"]])) > 2
+    for g, w in zip(got, want):
+        _assert_bit_identical(g, w)
 
 
 # --------------------------- k partitions per device (mesh, subprocess) ----

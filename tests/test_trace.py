@@ -311,6 +311,54 @@ def test_op_stages_maps_fused_ingest_program(tmp_path, profiled, n_parts):
         op.stage == "probe" and op.leaf for op in ops.values()))}
 
 
+@pytest.mark.parametrize("n_parts", [0, 2])
+def test_ingest_program_searches_keys_only_in_the_probe(tmp_path, profiled,
+                                                        n_parts):
+    """The re-sort branch reads each row's new slot off its own grouping:
+    outside the stream update's random draws, the compiled ingest program
+    holds one ``while`` (a binary search) per view, the probe's, and none
+    in ``touch_remap`` or ``relocate``."""
+    dur = _durable(tmp_path, n_parts=n_parts)
+    dur.ingest(_batch(64, 0))            # new keys: the re-sort branch
+    dur.commit()
+    dur.close()
+    ops = trace.op_stages("ingest")
+    (jitted, tree, sig), = trace._calls["ingest"].values()
+    whiles = [ops[name].stage
+              for name, op in _program_ops(jitted, tree, sig).items()
+              if not op.leaf and name.startswith("while")]
+    searches = [st for st in whiles if st != "stream"]
+    assert searches == ["probe", "probe"]            # base view and "ta"
+    staged = {op.stage for op in ops.values() if op.leaf}
+    assert {"touch_remap", "relocate"} <= staged
+
+
+def test_resort_merges_counts_views_of_the_re_sort_branch(tmp_path):
+    """``ingest.resort_merges`` counts, while a session records, the views
+    of each committed batch that took the re-sort branch: every view on a
+    batch of new keys, none on a correction of known rows."""
+    dur = _durable(tmp_path)
+    first, fresh = _batch(64, 0, x0_hi=2), _batch(64, 1, x0_hi=5)
+    trace.clear_spans()
+    dur.ingest(first)                    # new keys, not recording
+    dur.commit()
+    assert "ingest.resort_merges" not in trace.counters()
+    jax.profiler.start_trace(str(tmp_path / "trace"))
+    try:
+        rep = dur.ingest(fresh)          # x0 in 2..4 is new to each view
+        dur.commit()
+        on_new = trace.counters()["ingest.resort_merges"]
+        fix = dur.ingest(fresh, retract=True)
+        dur.commit()
+        on_fix = trace.counters()["ingest.resort_merges"] - on_new
+    finally:
+        jax.profiler.stop_trace()
+    dur.close()
+    assert not any(rep.fast_path.values()) and all(fix.fast_path.values())
+    assert on_new == len(rep.fast_path) == 2
+    assert on_fix == 0
+
+
 def _program_ops(jitted, tree, sig):
     """Instruction name -> OpStage of one remembered program."""
     args, kwargs = jax.tree.unflatten(tree, sig)

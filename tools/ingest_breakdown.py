@@ -18,7 +18,9 @@ one JSON object of:
 * ``host_ms``: per span name, the self time per batch of the program's
   spans, and the total per batch of the benchmark's own spans;
 * ``counters``: the program's recorder counters per batch
-  (``wal.records``, ``wal.bytes``, ``wal.fsyncs``) and in all
+  (``wal.records``, ``wal.bytes``, ``wal.fsyncs``, and
+  ``ingest.resort_merges``, the views that took the re-sort branch) and
+  in all
   (``ingest.redispatches``: 0 unless a batch grew a capacity, which
   runs a second program inside the window);
 * ``compile_spans``: compile spans recorded inside the window;
@@ -42,7 +44,8 @@ sys.path.insert(1, str(ROOT / "src"))
 from chipbench import harness, system, tracing  # noqa: E402
 from repro.launch import trace  # noqa: E402
 
-PER_BATCH = ("wal.records", "wal.bytes", "wal.fsyncs")
+PER_BATCH = ("wal.records", "wal.bytes", "wal.fsyncs",
+             "ingest.resort_merges")
 
 
 def main(argv=None) -> int:
